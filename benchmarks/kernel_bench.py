@@ -4,11 +4,10 @@ Two Pallas kernels are timed (interpret mode on CPU — a correctness /
 relative-cost sweep, not TPU performance; compiled Mosaic numbers come
 from running the same entry points on hardware):
 
-  * ``gain`` — the VMEM scoreboard (``kernels/gain``): dense (TILE_N, K)
-    gain tile accumulated DEG_CHUNK neighbours at a time.
-  * ``halo`` — the fused relayout+move-application kernel
-    (``kernels/halo``): permutation gather plus the O(P·ncand) gid-compare
-    move pass in one ``pallas_call``.
+  * ``gain`` — the VMEM scoreboard (``kernels/gain``): dense (K, TILE_N)
+    gain tile accumulated one neighbour row at a time.
+  * ``halo`` — the move-application kernel (``kernels/halo``): the
+    O(P·ncand) gid-compare move pass in one ``pallas_call``.
 
 This module owns the measured side of the autotune loop:
 :data:`SHAPES` is the default shape set and :func:`measure` the timing
@@ -45,7 +44,7 @@ import numpy as np
 
 from repro.kernels import tune
 from repro.kernels.gain.kernel import gain_scoreboard_pallas, round_up
-from repro.kernels.halo.kernel import halo_fused_pallas
+from repro.kernels.halo.kernel import halo_apply_pallas
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -87,10 +86,11 @@ def _gain_inputs(shape, tile_n: int, deg_chunk: int):
     key = ("gain", shape["name"], n_pad, d_pad, k_pad)
     if key not in _INPUT_MEMO:
         rng = np.random.default_rng(7)
-        nbr_lab = rng.integers(0, k, (n_pad, d_pad), dtype=np.int32)
-        nbr_lab[rng.random((n_pad, d_pad)) < 0.1] = int(PAD)  # ragged rows
-        nbr_lab[:, d:] = int(PAD)
-        nbr_w = rng.integers(1, 5, (n_pad, d_pad)).astype(np.float32)
+        # neighbour-major (D, N) layout, the kernel's input contract
+        nbr_lab = rng.integers(0, k, (d_pad, n_pad), dtype=np.int32)
+        nbr_lab[rng.random((d_pad, n_pad)) < 0.1] = int(PAD)  # ragged rows
+        nbr_lab[d:, :] = int(PAD)
+        nbr_w = rng.integers(1, 5, (d_pad, n_pad)).astype(np.float32)
         lab = rng.integers(0, k, (n_pad,), dtype=np.int32)
         nw = rng.integers(1, 4, (n_pad,)).astype(np.float32)
         cap = np.full((k_pad,), -np.inf, np.float32)
@@ -101,9 +101,9 @@ def _gain_inputs(shape, tile_n: int, deg_chunk: int):
 
 
 def _halo_inputs(shape):
-    """Deterministic halo-layout inputs for the fused kernel (labels in
-    block layout, interface-first permutation, move list), memoised per
-    shape.  Pad-independent: the jit wrapper pads to the tile grid."""
+    """Deterministic halo-layout inputs for the move kernel (labels,
+    per-slot global ids, move list), memoised per shape.  Pad-independent:
+    the jit wrapper pads to the tile grid."""
     n, c = shape["n"], shape["d"]
     key = ("halo", shape["name"])
     if key not in _INPUT_MEMO:
@@ -115,7 +115,7 @@ def _halo_inputs(shape):
         tgts = rng.integers(0, 8, (c,), dtype=np.int32)
         moved = (rng.random((c,)) < 0.5).astype(np.int32)
         _INPUT_MEMO[key] = tuple(jnp.asarray(a)
-                                 for a in (lab, perm, gid, tids, tgts, moved))
+                                 for a in (lab, gid, tids, tgts, moved))
     return _INPUT_MEMO[key]
 
 
@@ -129,9 +129,9 @@ def _bench_case(kernel: str, shape, cfg):
             nbr_lab, nbr_w, lab, nw, cap, tile_n=cfg["tile_n"],
             deg_chunk=cfg["deg_chunk"], interpret=interpret)
     if kernel == "halo":
-        lab, perm, gid, tids, tgts, moved = _halo_inputs(shape)
-        return lambda: halo_fused_pallas(
-            lab, perm, gid, tids, tgts, moved, tile_n=cfg["tile_n"],
+        lab, gid, tids, tgts, moved = _halo_inputs(shape)
+        return lambda: halo_apply_pallas(
+            lab, gid, tids, tgts, moved, tile_n=cfg["tile_n"],
             cand_chunk=cfg["cand_chunk"], interpret=interpret)
     raise ValueError(f"unknown kernel {kernel!r}; have {sorted(SHAPES)}")
 

@@ -23,7 +23,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-# shared runtime hygiene (tcmalloc, TF log level, TPU-gated XLA flags)
+# shared runtime hygiene (tcmalloc, TF log level)
 source scripts/run_env.sh
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"

@@ -2,17 +2,18 @@
 # and the bench harnesses all `source` this (olmax / HomebrewNLP-Jax run.sh
 # lineage).  Rules:
 #
-#   * additive only: appends to XLA_FLAGS and never overrides a variable
-#     the caller already exported (forced host-device counts in tests/CI
-#     must win);
+#   * additive only: never overrides a variable the caller already
+#     exported;
 #   * never sets JAX_ENABLE_X64 — fp64 would break the fp32-exactness
 #     determinism contract (DESIGN.md §3);
-#   * every knob is guarded: a container without tcmalloc or a TPU gets a
-#     no-op, not a broken interpreter (this XLA CPU build hard-aborts on
-#     unknown XLA_FLAGS, so TPU-only flags are gated on a TPU actually
-#     being present).
+#   * every knob is guarded: a machine without tcmalloc gets a no-op, not
+#     a broken interpreter;
+#   * no XLA_FLAGS of its own: the installed jaxlib parses XLA_FLAGS once
+#     for every backend and hard-aborts on a flag it does not know (the
+#     TPU step-marker flag among them), and TPU compiler flags belong in
+#     LIBTPU_INIT_ARGS, which this file never touches.
 
-# faster malloc when the container ships it; skipped silently otherwise
+# faster malloc where the machine ships it; skipped silently otherwise
 if [ -z "${LD_PRELOAD:-}" ]; then
   for _repro_tcmalloc in \
       /usr/lib/x86_64-linux-gnu/libtcmalloc.so.4 \
@@ -30,13 +31,3 @@ fi
 
 # quiet TF/XLA C++ logging (dataset + compilation chatter)
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
-
-# TPU-only flags: step markers at the outer while loop make per-level
-# profiles/rooflines attributable.  The CPU XLA build rejects the flag
-# (hard abort at import), so gate on a TPU being visible.
-if [ -e /dev/accel0 ] || [ -n "${TPU_NAME:-}" ]; then
-  case " ${XLA_FLAGS:-} " in
-    *"--xla_step_marker_location="*) : ;;
-    *) export XLA_FLAGS="--xla_step_marker_location=1${XLA_FLAGS:+ $XLA_FLAGS}" ;;
-  esac
-fi

@@ -9,6 +9,7 @@ from repro.core.partition import (  # noqa: F401
     block_weights,
     conn_dense,
     edge_cut,
+    exact_edge_cut,
     imbalance,
     l_max,
     total_overload,

@@ -26,18 +26,20 @@ def grouped_best_cluster(src, cl_dst, w, *, n: int, m: int):
     """Array-level core of the LP scoring: per tail vertex, the strongest
     neighbouring cluster over (src, cluster[dst]) groups.
 
-    Grouped reduction over lexsorted pairs; ties broken by smallest cluster
-    id (determinism).  Shared bit-for-bit by the host path below and the
-    per-PE sharded path (distributed/dcoarsen.py) — the sharded==host
-    equivalence tests depend on both calling exactly this.
+    Grouped reduction over (src, cluster)-sorted pairs; ties broken by
+    smallest cluster id (determinism).  Shared bit-for-bit by the host path
+    below and the per-PE sharded path (distributed/dcoarsen.py) — the
+    sharded==host equivalence tests depend on both calling exactly this.
+    The sort is unstable — the order inside a group only changes the order
+    of a fp32 sum, exact on integer weights — because a stable 2-key TPU
+    sort compiles for minutes at 2^20 slots.
 
     Returns (best_cl, has, best_conn); ``best_cl`` is int32::max where a
     vertex has no live group (caller substitutes its current cluster).
     """
-    order = jnp.lexsort((cl_dst, src))
-    src_s = src[order]
-    cl_s = cl_dst[order]
-    w_s = w[order]
+    src_s, cl_s, w_s = jax.lax.sort(
+        (src.astype(jnp.int32), cl_dst.astype(jnp.int32), w), num_keys=2,
+        is_stable=False)
 
     first = jnp.concatenate(
         [jnp.array([True]), (src_s[1:] != src_s[:-1]) | (cl_s[1:] != cl_s[:-1])]
@@ -60,16 +62,22 @@ def grouped_best_cluster(src, cl_dst, w, *, n: int, m: int):
     return best_cl, has, vmax
 
 
-@partial(jax.jit, static_argnames=())
-def _best_neighbor_cluster(g: Graph, cluster: jax.Array):
-    """For each vertex: (best_cluster, best_conn) over neighbouring clusters."""
-    cl_dst = cluster[g.safe_col()]
-    w = jnp.where(g.edge_mask, g.ew, 0.0)
-    # exclude self-cluster edges from "join" scoring? No: conn to own cluster
-    # competes fairly (a vertex stays if its own cluster is strongest).
-    best_cl, has, vmax = grouped_best_cluster(g.src, cl_dst, w, n=g.n, m=g.m)
-    best_cl = jnp.where(has, best_cl, cluster)
-    return best_cl.astype(jnp.int32), vmax
+def joiner_mask(coin):
+    """The round's coin flip: ``True`` for vertices that may join another
+    cluster this round, ``False`` for *receivers*, which stay put and may
+    be joined.  ``coin`` is the round's second global-vertex-space draw
+    (``fold_in(key, 1)``).  Without it every vertex of a uniform mesh moves
+    to its smallest-id neighbour at once, the moves chase each other, and
+    a 2^16-vertex grid shrinks by < 5 % per level — the V-cycle stopped at
+    one level (DESIGN.md §3)."""
+    return coin < 0.5
+
+
+def mask_joiner_targets(w, cl_dst, cl_src, joiner_dst):
+    """Zero the weight of edges into *other* clusters whose leader is a
+    joiner this round, so the best-cluster search only sees receivers (and
+    the vertex's own cluster, at full weight)."""
+    return jnp.where(joiner_dst & (cl_dst != cl_src), 0.0, w)
 
 
 @partial(jax.jit, static_argnames=())
@@ -79,10 +87,19 @@ def cluster_round(
     cl_weight_cap: jax.Array,
     key: jax.Array,
 ):
-    """One LP clustering round with probabilistic size-cap admission."""
-    best_cl, best_conn = _best_neighbor_cluster(g, cluster)
+    """One LP clustering round with probabilistic size-cap admission: only
+    this round's joiners move, and only into receivers' clusters
+    (:func:`joiner_mask`)."""
+    joiner = joiner_mask(jax.random.uniform(jax.random.fold_in(key, 1),
+                                            (g.n,)))
+    cl_dst = cluster[g.safe_col()]
+    w = jnp.where(g.edge_mask, g.ew, 0.0)
+    w = mask_joiner_targets(w, cl_dst, cluster[g.src], joiner[cl_dst])
+    best_cl, has, best_conn = grouped_best_cluster(g.src, cl_dst, w, n=g.n,
+                                                   m=g.m)
+    best_cl = jnp.where(has, best_cl, cluster).astype(jnp.int32)
     cl_w = jax.ops.segment_sum(g.nw, cluster, num_segments=g.n)
-    want = (best_cl != cluster) & (best_conn > 0)
+    want = joiner & (best_cl != cluster) & (best_conn > 0)
     want &= cl_w[best_cl] + g.nw <= cl_weight_cap
 
     # in-expectation cap: admit into cluster c with prob room_c / inflow_c

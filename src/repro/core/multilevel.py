@@ -24,7 +24,7 @@ from repro.core import coarsen as C
 from repro.core.config import UNSET, PartitionConfig, resolve_config
 from repro.core.graph import Graph
 from repro.core.initial import initial_partition
-from repro.core.partition import edge_cut, imbalance
+from repro.core.partition import exact_edge_cut, imbalance
 from repro.core.refine import jet_refine, lp_refine_level
 from repro.refine.drivers import level_tolerances
 from repro.refine.schedule import ToleranceSchedule, weight_frac
@@ -181,7 +181,7 @@ def partition(
 
     return PartitionResult(
         labels=labels,
-        cut=float(edge_cut(g, labels)),
+        cut=exact_edge_cut(g, labels),
         imbalance=float(imbalance(g, labels, k)),
         levels=n_levels,
         level_eps=eps_l,
@@ -388,7 +388,7 @@ def finalize_result(s: dict, k: int, trace_levels: bool) -> PartitionResult:
     """Materialize one work item's result — the host sync point."""
     return PartitionResult(
         labels=s["labels"],
-        cut=float(edge_cut(s["g"], s["labels"])),
+        cut=exact_edge_cut(s["g"], s["labels"]),
         imbalance=float(imbalance(s["g"], s["labels"], k)),
         levels=s["n_levels"],
         level_eps=s["eps_l"],

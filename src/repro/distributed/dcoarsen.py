@@ -43,7 +43,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.coarsen import grouped_best_cluster
+from repro.core.coarsen import (
+    grouped_best_cluster,
+    joiner_mask,
+    mask_joiner_targets,
+)
 from repro.core.graph import PAD
 from repro.distributed.dgraph import ShardedGraph, owned_mask
 from repro.distributed.djet import _gather, _global_uniform
@@ -63,6 +67,12 @@ def dcluster_round_local(src, dst, ew, nw, owned, cl, gstart, key, cap,
     """
     n_pad = P_ * n_local
     cl_full = _gather(cl)
+    # the round's coin flip in global vertex space, gathered so it can be
+    # read at any cluster leader's gathered-layout id
+    coin = _global_uniform(jax.random.fold_in(key, 1), gstart,
+                           n_local=n_local, n_real=n_real)
+    joiner = joiner_mask(coin)
+    joiner_full = _gather(joiner)
 
     # best neighbouring cluster: the host path's grouped reduction, applied
     # to this PE's contiguous edge range (bit-identical group sums — the
@@ -70,6 +80,7 @@ def dcluster_round_local(src, dst, ew, nw, owned, cl, gstart, key, cap,
     live = dst != PAD
     cl_dst = cl_full[jnp.where(live, dst, 0)]
     w = jnp.where(live, ew, 0.0)
+    w = mask_joiner_targets(w, cl_dst, cl[src], joiner_full[cl_dst])
     best_cl, has, best_conn = grouped_best_cluster(
         src, cl_dst, w, n=n_local, m=m_local
     )
@@ -80,7 +91,7 @@ def dcluster_round_local(src, dst, ew, nw, owned, cl, gstart, key, cap,
         jax.ops.segment_sum(jnp.where(owned, nw, 0.0), cl, num_segments=n_pad),
         "pe",
     )
-    want = (best_cl != cl) & (best_conn > 0) & owned
+    want = joiner & (best_cl != cl) & (best_conn > 0) & owned
     want &= clw[best_cl] + nw <= cap
     inflow = jax.lax.psum(
         jax.ops.segment_sum(jnp.where(want, nw, 0.0), best_cl, num_segments=n_pad),
@@ -99,6 +110,31 @@ def dcompress_local(cl):
     """Leader path-compression ``cl = cl[cl]`` with one ghost gather."""
     cl_full = _gather(cl)
     return cl_full[cl]
+
+
+# up to this many PEs the bucket rank is a counting sort (one cumulative
+# count per PE, O(P·m) elementwise work and no device sort, whose compile
+# dominates a level on the TPU); above it, one stable argsort, O(m log m)
+COUNTING_SORT_MAX_P = 8
+
+
+def bucket_rank(dest: jax.Array, P_: int) -> jax.Array:
+    """Rank of each slot among the slots of its bucket, in slot order
+    (``dest`` in [0, P_]; bucket ``P_`` is the dropped one and its ranks are
+    unspecified).  The same ranks as a stable sort by ``dest``."""
+    m = dest.shape[0]
+    if P_ <= COUNTING_SORT_MAX_P:
+        pos = jnp.zeros((m,), jnp.int32)
+        for d in range(P_):
+            in_d = dest == d
+            pos = jnp.where(in_d, jnp.cumsum(in_d.astype(jnp.int32)) - 1, pos)
+        return pos
+    order = jnp.argsort(dest, stable=True)
+    counts = jnp.zeros((P_ + 1,), jnp.int32).at[dest].add(1)
+    starts = jnp.cumsum(counts) - counts
+    rank = jnp.zeros((m,), jnp.int32).at[order].set(
+        jnp.arange(m, dtype=jnp.int32))
+    return rank - starts[dest]
 
 
 def dcontract_local(src, dst, ew, nw, owned, cl,
@@ -138,26 +174,15 @@ def dcontract_local(src, dst, ew, nw, owned, cl,
     w = jnp.where(keep, ew, 0.0)
 
     # bucket by new owner of the coarse tail and pack the all_to_all buffer:
-    # stable sort by destination PE, then scatter each edge to
-    # (dest, rank-within-bucket).  A PE holds ≤ m_local live edges, so every
-    # bucket fits the m_local-wide send row.
+    # each edge goes to (dest, rank-within-bucket), the rank counted in slot
+    # order.  A PE holds ≤ m_local live edges, so every bucket fits the
+    # m_local-wide send row.
     dest = jnp.where(keep, cu // blk, P_)
-    order = jnp.argsort(dest, stable=True)
-    d_s = dest[order]
-    cu_s = cu[order]
-    cv_s = cv[order]
-    w_s = w[order]
-    counts = jax.ops.segment_sum(
-        jnp.ones((m_local,), jnp.int32), d_s, num_segments=P_ + 1
-    )
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)]
-    )
-    pos = jnp.arange(m_local, dtype=jnp.int32) - starts[d_s]
-    flat = jnp.where(d_s < P_, d_s * m_local + pos, P_ * m_local)
-    send_cu = jnp.full((P_ * m_local,), -1, jnp.int32).at[flat].set(cu_s, mode="drop")
-    send_cv = jnp.zeros((P_ * m_local,), jnp.int32).at[flat].set(cv_s, mode="drop")
-    send_w = jnp.zeros((P_ * m_local,), jnp.float32).at[flat].set(w_s, mode="drop")
+    pos = bucket_rank(dest, P_)
+    flat = jnp.where(dest < P_, dest * m_local + pos, P_ * m_local)
+    send_cu = jnp.full((P_ * m_local,), -1, jnp.int32).at[flat].set(cu, mode="drop")
+    send_cv = jnp.zeros((P_ * m_local,), jnp.int32).at[flat].set(cv, mode="drop")
+    send_w = jnp.zeros((P_ * m_local,), jnp.float32).at[flat].set(w, mode="drop")
 
     shp = (P_, m_local)
     rcu = jax.lax.all_to_all(send_cu.reshape(shp), "pe", 0, 0, tiled=True).reshape(-1)
@@ -166,16 +191,17 @@ def dcontract_local(src, dst, ew, nw, owned, cl,
 
     # coalesce parallel edges: sort received slots by (row, head), grouped
     # segment sums, groups compacted to the front in CSR order (sorted by
-    # head within each row — the same canonical order from_coo produces)
+    # head within each row — the same canonical order from_coo produces).
+    # Empty slots sort last under an int32::max row; the sort is unstable
+    # (inside a group it only reorders a fp32 sum, exact on integer weights)
     R = P_ * m_local
+    imax = jnp.iinfo(jnp.int32).max
     valid = rcu >= 0
-    row = jnp.where(valid, rcu - pe * blk, 0)
-    colc = jnp.where(valid, rcv, 0)
-    order2 = jnp.lexsort((colc, row, (~valid).astype(jnp.int32)))
-    vS = valid[order2]
-    rowS = row[order2]
-    colS = colc[order2]
-    wS = rw[order2]
+    row = jnp.where(valid, rcu - pe * blk, imax)
+    colc = jnp.where(valid, rcv, imax)
+    rowS, colS, wS = jax.lax.sort((row, colc, rw), num_keys=2,
+                                  is_stable=False)
+    vS = rowS != imax
     first = vS & jnp.concatenate(
         [jnp.array([True]), (rowS[1:] != rowS[:-1]) | (colS[1:] != colS[:-1])]
     )
@@ -206,7 +232,7 @@ def _specs(n: int):
 def _cluster_round_fn(mesh, P_: int, n_local: int, m_local: int, n_real: int):
     from repro.sharding.compat import shard_map
 
-    def per_pe(src, dst, ew, nw, owned, cl, gstart, key, cap):
+    def cluster_round(src, dst, ew, nw, owned, cl, gstart, key, cap):
         new_cl, moved = dcluster_round_local(
             src[0], dst[0], ew[0], nw[0], owned[0], cl[0], gstart[0], key, cap,
             P_=P_, n_local=n_local, m_local=m_local, n_real=n_real,
@@ -214,7 +240,7 @@ def _cluster_round_fn(mesh, P_: int, n_local: int, m_local: int, n_real: int):
         return new_cl[None], moved
 
     return jax.jit(shard_map(
-        per_pe, mesh=mesh,
+        cluster_round, mesh=mesh,
         in_specs=_specs(6) + (P("pe"), P(), P()),
         out_specs=(P("pe", None), P()),
     ))
@@ -224,11 +250,11 @@ def _cluster_round_fn(mesh, P_: int, n_local: int, m_local: int, n_real: int):
 def _compress_fn(mesh, n_local: int):
     from repro.sharding.compat import shard_map
 
-    def per_pe(cl):
+    def compress(cl):
         return dcompress_local(cl[0])[None]
 
     return jax.jit(shard_map(
-        per_pe, mesh=mesh, in_specs=_specs(1), out_specs=P("pe", None)
+        compress, mesh=mesh, in_specs=_specs(1), out_specs=P("pe", None)
     ))
 
 
@@ -236,7 +262,7 @@ def _compress_fn(mesh, n_local: int):
 def _contract_fn(mesh, P_: int, n_local: int, m_local: int, blk: int):
     from repro.sharding.compat import shard_map
 
-    def per_pe(src, dst, ew, nw, owned, cl):
+    def contract(src, dst, ew, nw, owned, cl):
         src_c, dst_c, ew_c, nw_c, map_loc, mmax = dcontract_local(
             src[0], dst[0], ew[0], nw[0], owned[0], cl[0],
             P_=P_, n_local=n_local, m_local=m_local, blk=blk,
@@ -244,7 +270,7 @@ def _contract_fn(mesh, P_: int, n_local: int, m_local: int, blk: int):
         return src_c[None], dst_c[None], ew_c[None], nw_c[None], map_loc[None], mmax
 
     return jax.jit(shard_map(
-        per_pe, mesh=mesh,
+        contract, mesh=mesh,
         in_specs=_specs(6),
         out_specs=_specs(5) + (P(),),
     ))
@@ -254,13 +280,13 @@ def _contract_fn(mesh, P_: int, n_local: int, m_local: int, blk: int):
 def _uncoarsen_fn(mesh, n_local_f: int, blk: int):
     from repro.sharding.compat import shard_map
 
-    def per_pe(map_loc, owned, lab_c):
+    def uncoarsen(map_loc, owned, lab_c):
         lab_full = _gather(lab_c[0])
         out = jnp.where(owned[0], lab_full[map_loc[0]], 0)
         return out[None].astype(jnp.int32)
 
     return jax.jit(shard_map(
-        per_pe, mesh=mesh, in_specs=_specs(3), out_specs=P("pe", None)
+        uncoarsen, mesh=mesh, in_specs=_specs(3), out_specs=P("pe", None)
     ))
 
 
@@ -286,7 +312,11 @@ def dcluster(mesh, sg: ShardedGraph, weight_cap: float, key,
     same early-out on a zero moved-count)."""
     fn = _cluster_round_fn(mesh, sg.P, sg.n_local, sg.m_local, sg.n_real)
     owned = owned_mask(sg)
-    cl = jnp.arange(sg.P * sg.n_local, dtype=jnp.int32).reshape(sg.P, sg.n_local)
+    # placed as the round's output is, so round 1 and rounds 2.. share one
+    # compiled program (an unplaced first ``cl`` compiled the round twice)
+    cl = jax.device_put(
+        jnp.arange(sg.P * sg.n_local, dtype=jnp.int32).reshape(sg.P, sg.n_local),
+        jax.sharding.NamedSharding(mesh, P("pe", None)))
     cap = jnp.asarray(weight_cap, jnp.float32)
     for _ in range(rounds):
         key, sub = jax.random.split(key)

@@ -97,8 +97,13 @@ def gathered_ids(heads: np.ndarray, owner_starts: np.ndarray,
     return owner * n_local + (heads - owner_starts[owner])
 
 
-def shard_graph(g: Graph, P: int) -> ShardedGraph:
-    """Host-side partition of ``g`` into P contiguous, edge-balanced ranges."""
+def shard_graph(g: Graph, P: int, mesh=None) -> ShardedGraph:
+    """Host-side partition of ``g`` into P contiguous, edge-balanced ranges.
+
+    With ``mesh`` (a 1-D ``"pe"`` mesh of P devices) every (P, ...) array
+    goes from the host straight to its PE's device — no whole-graph copy
+    lands on the first device on the way; without it the arrays take the
+    default device and the level programs re-shard them."""
     row_ptr = np.asarray(g.row_ptr, dtype=np.int64)
     starts, n_local, m_local = shard_plan(row_ptr, g.n, P)
 
@@ -128,12 +133,18 @@ def shard_graph(g: Graph, P: int) -> ShardedGraph:
         ew[p, :cnt] = gew[e0:e1]
         nw[p, : v1 - v0] = gnw[v0:v1]
 
+    if mesh is None:
+        put = jnp.asarray
+    else:
+        sharding = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec("pe"))
+        put = lambda x: jax.device_put(x, sharding)  # noqa: E731
     return ShardedGraph(
-        src=jnp.asarray(src),
-        dst=jnp.asarray(dst),
-        ew=jnp.asarray(ew),
-        nw=jnp.asarray(nw),
-        vtx_start=jnp.asarray(starts[:P].astype(np.int32)),
+        src=put(src),
+        dst=put(dst),
+        ew=put(ew),
+        nw=put(nw),
+        vtx_start=put(starts[:P].astype(np.int32)),
         n_real=g.n,
         P=P,
         n_local=n_local,
@@ -207,17 +218,24 @@ def sharded_to_graph(sg: ShardedGraph) -> Graph:
     return from_coo(sg.n_real, u, v, w, nw=nw, symmetrize=False)
 
 
-def sharded_edge_cut(sg: ShardedGraph, lab_sh: jax.Array) -> jax.Array:
+def sharded_edge_cut(sg: ShardedGraph, lab_sh: jax.Array) -> float:
     """Edge cut from the sharded layout alone (no host Graph needed — the
     out-of-core ingest path's metric).  ``lab_sh`` is (P, n_local)
     owner-sharded labels; each undirected edge is stored as two directed
-    copies, so the masked sum halves exactly like ``core.partition.edge_cut``
+    copies, so the masked sum halves like ``core.partition.exact_edge_cut``
     (bit-equal on integer weights; summation order may differ otherwise)."""
+    from repro.core.partition import half_sum_exact
+
+    return half_sum_exact(_sharded_cut_weights(sg, lab_sh))
+
+
+@jax.jit
+def _sharded_cut_weights(sg: ShardedGraph, lab_sh: jax.Array) -> jax.Array:
     lab_g = lab_sh.reshape(-1)  # gathered layout: PE p's vertex i at p·n_local+i
     src_lab = jnp.take_along_axis(lab_sh, sg.src, axis=1)
     live = sg.dst != PAD
     dst_lab = lab_g[jnp.where(live, sg.dst, 0)]
-    return jnp.sum(jnp.where(live & (src_lab != dst_lab), sg.ew, 0.0)) * 0.5
+    return jnp.where(live & (src_lab != dst_lab), sg.ew, 0.0)
 
 
 def sharded_imbalance(sg: ShardedGraph, lab_sh: jax.Array, k: int):

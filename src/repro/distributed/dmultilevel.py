@@ -43,7 +43,7 @@ from repro.core.config import UNSET, PartitionConfig, resolve_config
 from repro.core.graph import PAD, Graph
 from repro.core.initial import initial_partition
 from repro.core.multilevel import level_trace_entry
-from repro.core.partition import edge_cut, imbalance, l_max
+from repro.core.partition import exact_edge_cut, imbalance, l_max
 from repro.core.refine import temperature_schedule
 from repro.distributed.dcoarsen import dcoarsen_hierarchy, duncoarsen
 from repro.distributed.dgraph import (
@@ -84,6 +84,10 @@ class DPartitionResult:
     level_trace: tuple | None = None
     # committed snapshot step the V-cycle restarted from (None = fresh run)
     resume_step: int | None = None
+    # per refined level (coarsest → finest): {n, gain, halo} — the backends
+    # the envelope rules resolved (halo None under the all-gather protocol),
+    # so a fallback from the Pallas kernels to jnp is visible
+    level_backends: tuple = ()
 
 
 class _PhaseTimer:
@@ -143,7 +147,8 @@ def _drefine_sharded(mesh, sg: ShardedGraph, lab_sh, k, lmax, key,
     program) runs over whichever comm backend the level uses.  With ``hsg``
     set, the level runs under the interface-only halo protocol: labels
     convert to the interface-first layout with a per-PE device gather,
-    refine, and convert back — still one dispatch for the level program."""
+    refine, and convert back — still one dispatch for the level program.
+    Returns ``(labels, backends)`` — the resolved gain/halo backends."""
     taus = temperature_schedule(var.rounds)
     if hsg is not None:
         # relayout=True fuses the halo↔block label conversions into the
@@ -155,17 +160,18 @@ def _drefine_sharded(mesh, sg: ShardedGraph, lab_sh, k, lmax, key,
             mesh, hsg, k, rounds_taus=taus,
             patience=patience, max_inner=max_inner, gain=gain,
             uniform_mode=halo_uniform, variant=var.name, relayout=True)
-        return run(lab_sh, key, lmax)
-    run = make_refine_level_sharded(
-        mesh, sg, k, rounds_taus=taus,
-        patience=patience, max_inner=max_inner, gain=gain, variant=var.name)
-    return run(lab_sh, key, lmax)
+    else:
+        run = make_refine_level_sharded(
+            mesh, sg, k, rounds_taus=taus, patience=patience,
+            max_inner=max_inner, gain=gain, variant=var.name)
+    return run(lab_sh, key, lmax), run.backends
 
 
 def _drefine_level(mesh, g: Graph, labels, k, eps, key, var: Variant,
                    patience, max_inner, halo: bool = False, gain="jnp",
                    halo_uniform="global"):
-    """Host-path level refinement: shard the level graph, refine, gather."""
+    """Host-path level refinement: shard the level graph, refine, gather.
+    Returns ``(labels, backends)`` like :func:`_drefine_sharded`."""
     P_ = mesh.devices.size
     lmax = l_max(g, k, eps)
 
@@ -185,13 +191,13 @@ def _drefine_level(mesh, g: Graph, labels, k, eps, key, var: Variant,
             patience=patience, max_inner=max_inner, gain=gain,
             uniform_mode=halo_uniform, variant=var.name)
         lab_sh = run(lab_sh, key, lmax)
-        return halo_labels_from_sharded(hsg, perm, lab_sh)
+        return halo_labels_from_sharded(hsg, perm, lab_sh), run.backends
 
-    sg = shard_graph(g, P_)
+    sg = shard_graph(g, P_, mesh=mesh)
     lab_sh = labels_to_sharded(sg, labels)
-    lab_sh = _drefine_sharded(mesh, sg, lab_sh, k, lmax, key, var,
-                              patience, max_inner, gain=gain)
-    return labels_from_sharded(sg, lab_sh)
+    lab_sh, backends = _drefine_sharded(mesh, sg, lab_sh, k, lmax, key, var,
+                                        patience, max_inner, gain=gain)
+    return labels_from_sharded(sg, lab_sh), backends
 
 
 def _dpartition_host_coarsen(mesh, g, k, eps, key, k_coarse, k_init, var,
@@ -228,6 +234,7 @@ def _dpartition_host_coarsen(mesh, g, k, eps, key, k_coarse, k_init, var,
             vc.save_step(policy, 0, labels, key, fp)
 
     trace: list[dict] = []
+    backends: list[dict] = []
 
     def _record(lvl_g, lab, e):
         if trace_levels:
@@ -239,14 +246,15 @@ def _dpartition_host_coarsen(mesh, g, k, eps, key, k_coarse, k_init, var,
         if j > 0:
             labels = labels[mappings[j - 1]]
         key, sub = jax.random.split(key)
-        labels = _drefine_level(mesh, level_graphs[j], labels, k, eps_l[j],
-                                sub, var, patience, max_inner, halo=halo,
-                                gain=gain, halo_uniform=halo_uniform)
+        labels, used = _drefine_level(
+            mesh, level_graphs[j], labels, k, eps_l[j], sub, var, patience,
+            max_inner, halo=halo, gain=gain, halo_uniform=halo_uniform)
+        backends.append(dict(n=level_graphs[j].n, **used))
         _record(level_graphs[j], labels, eps_l[j])
         if policy is not None and policy.want_step(j, n_levels):
             vc.save_step(policy, j + 1, labels, key, fp)
     timer.stop("refine_s", labels)
-    return labels, n_levels, eps_l, trace, resume_step
+    return labels, n_levels, eps_l, trace, resume_step, backends
 
 
 def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
@@ -262,7 +270,8 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
     ``g`` may already be a :class:`ShardedGraph` (the out-of-core ingest
     path) — it is used as-is instead of re-sharding a host Graph."""
     P_ = mesh.devices.size
-    sg0 = g if isinstance(g, ShardedGraph) else shard_graph(g, P_)
+    sg0 = (g if isinstance(g, ShardedGraph)
+           else shard_graph(g, P_, mesh=mesh))
     timer.start(sg0.nw)
     if halo:
         levels, coarsest, halos = dcoarsen_hierarchy(
@@ -306,6 +315,7 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
                          key, fp)
 
     trace: list[dict] = []
+    backends: list[dict] = []
 
     def _record(lvl_sg, lab, e):
         if trace_levels:
@@ -322,10 +332,11 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
             lab_sh = duncoarsen(mesh, fine_sg, map_sh, coarse_sg, lab_sh)
             sg_j, hs = fine_sg, halos[i]
         key, sub = jax.random.split(key)
-        lab_sh = _drefine_sharded(mesh, sg_j, lab_sh, k,
-                                  _dl_max(sg_j, k, eps_l[j]), sub, var,
-                                  patience, max_inner, gain=gain, hsg=hs,
-                                  halo_uniform=halo_uniform)
+        lab_sh, used = _drefine_sharded(
+            mesh, sg_j, lab_sh, k, _dl_max(sg_j, k, eps_l[j]), sub, var,
+            patience, max_inner, gain=gain, hsg=hs,
+            halo_uniform=halo_uniform)
+        backends.append(dict(n=sg_j.n_real, **used))
         _record(sg_j, lab_sh, eps_l[j])
         if policy is not None and policy.want_step(j, n_levels):
             vc.save_step(policy, j + 1, labels_from_sharded(sg_j, lab_sh),
@@ -333,7 +344,7 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
     timer.stop("refine_s", lab_sh)
 
     return labels_from_sharded(sg0, lab_sh), n_levels, eps_l, trace, \
-        resume_step
+        resume_step, backends
 
 
 def dpartition(
@@ -437,13 +448,13 @@ def dpartition(
         fp = vc.fingerprint(cfg, seed, n_g, m_live)
 
     if coarsen == "host":
-        labels, n_levels, eps_l, trace, resume_step = \
+        labels, n_levels, eps_l, trace, resume_step, backends = \
             _dpartition_host_coarsen(
                 mesh, g, k, eps, key, k_coarse, k_init, var, coarsen_until,
                 patience, max_inner, halo, gain, halo_uniform, timer, sched,
                 trace_levels, policy=policy, resume=resume, fp=fp)
     else:
-        labels, n_levels, eps_l, trace, resume_step = \
+        labels, n_levels, eps_l, trace, resume_step, backends = \
             _dpartition_sharded_coarsen(
                 mesh, g, k, eps, key, k_coarse, k_init, var, coarsen_until,
                 patience, max_inner, halo, gain, halo_uniform, timer, sched,
@@ -451,10 +462,10 @@ def dpartition(
 
     if sharded_in:
         lab_fin = labels_to_sharded(g, labels)
-        cut = float(sharded_edge_cut(g, lab_fin))
+        cut = sharded_edge_cut(g, lab_fin)
         imb = float(sharded_imbalance(g, lab_fin, k))
     else:
-        cut = float(edge_cut(g, labels))
+        cut = exact_edge_cut(g, labels)
         imb = float(imbalance(g, labels, k))
     return DPartitionResult(
         labels=labels,
@@ -466,4 +477,5 @@ def dpartition(
         level_eps=eps_l,
         level_trace=tuple(trace) if trace_levels else None,
         resume_step=resume_step,
+        level_backends=tuple(backends),
     )

@@ -110,12 +110,19 @@ def _interface_perm_local(iface, owned, *, n_local: int):
     """Interface-first permutation of one PE's slots.
 
     Returns (perm_loc, inv, n_if): the halo→block slot map, its inverse and
-    the interface count.  The stable sort on class (interface, interior,
-    padding) keeps ascending slot — i.e. ascending global id — order inside
-    each class, so the layout matches the host-side construction exactly."""
+    the interface count.  The stable counting sort on class (interface,
+    interior, padding) keeps ascending slot — i.e. ascending global id —
+    order inside each class, so the layout matches the host-side
+    construction exactly; it is three cumulative counts, not a device
+    sort."""
     cls = jnp.where(iface, 0, jnp.where(owned, 1, 2)).astype(jnp.int32)
-    perm_loc = jnp.argsort(cls, stable=True).astype(jnp.int32)
-    inv = jnp.zeros((n_local,), jnp.int32).at[perm_loc].set(
+    inv = jnp.zeros((n_local,), jnp.int32)
+    start = jnp.int32(0)
+    for c in range(3):
+        in_c = (cls == c).astype(jnp.int32)
+        inv = jnp.where(cls == c, start + jnp.cumsum(in_c) - 1, inv)
+        start = start + jnp.sum(in_c)
+    perm_loc = jnp.zeros((n_local,), jnp.int32).at[inv].set(
         jnp.arange(n_local, dtype=jnp.int32))
     return perm_loc, inv, jnp.sum(iface.astype(jnp.int32))
 
@@ -268,36 +275,22 @@ def halo_labels_from_sharded(sg: HaloShardedGraph, perm: np.ndarray, lab_sh):
     return jnp.asarray(out)
 
 
-def block_labels_to_halo(hsg: HaloShardedGraph, lab_sh, *,
-                         kernel: str = "jnp", interpret: bool | None = None):
+def block_labels_to_halo(hsg: HaloShardedGraph, lab_sh):
     """(P, n_local) block-layout labels → halo (interface-first) layout.
 
     A per-PE gather through ``perm_loc`` — device-resident, this is how
     ``duncoarsen`` output flows straight into the halo level program.
-    ``kernel="pallas"`` routes the gather through the VMEM relayout kernel
-    (``repro.kernels.halo.relayout``) — same values, it only moves labels.
     The sharded V-cycle no longer calls this between dispatches: the
     conversion is fused *into* the level program
     (``drivers.make_refine_level_halo(relayout=True)``); this standalone
     entry serves the host paths, benchmarks and tests."""
-    if kernel == "pallas":
-        from repro.kernels.halo import relayout
-
-        return jax.vmap(lambda x, p: relayout(x, p, interpret=interpret))(
-            lab_sh, hsg.perm_loc)
     return jnp.take_along_axis(lab_sh, hsg.perm_loc, axis=1)
 
 
-def block_labels_from_halo(hsg: HaloShardedGraph, lab_h, *,
-                           kernel: str = "jnp", interpret: bool | None = None):
-    """Halo layout → (P, n_local) block layout.  The scatter through
-    ``perm_loc`` is the gather through ``inv_perm`` (the permutation is
-    total), which is how the kernel path renders it."""
-    if kernel == "pallas":
-        from repro.kernels.halo import relayout
-
-        return jax.vmap(lambda x, p: relayout(x, p, interpret=interpret))(
-            lab_h, hsg.inv_perm)
+def block_labels_from_halo(hsg: HaloShardedGraph, lab_h):
+    """Halo layout → (P, n_local) block layout: the scatter through
+    ``perm_loc`` (equivalently the gather through ``inv_perm`` — the
+    permutation is total)."""
     rows = jnp.arange(hsg.P, dtype=jnp.int32)[:, None]
     return jnp.zeros_like(lab_h).at[rows, hsg.perm_loc].set(lab_h)
 

@@ -165,9 +165,9 @@ def watts_strogatz(n: int, k: int = 6, beta: float = 0.1, seed: int = 0) -> Grap
 
 
 # --------------------------------------------------------------------------
-# Benchmark registry — mirrors the paper's Table 2 classes at CPU scale.
-# name -> (factory, kwargs, class) ; sizes chosen to run the full multilevel
-# pipeline in seconds on one CPU device.
+# Benchmark registry — mirrors the paper's Table 2 classes.
+# name -> (factory, kwargs, class) ; most sizes run the full multilevel
+# pipeline in seconds on one CPU device, the *_8m/*_21 ones are chip-scale.
 # --------------------------------------------------------------------------
 BENCHMARK_SET = {
     # low-degree / mesh-like (paper: nlpkkt240, europe.osm, rgg*, del*)
@@ -182,6 +182,15 @@ BENCHMARK_SET = {
     "rmat_14": (rmat, dict(scale=14, edge_factor=8, seed=5), "high"),
     "rmat_15": (rmat, dict(scale=15, edge_factor=6, seed=6), "high"),
     "ws_16k": (watts_strogatz, dict(n=16384, k=8, beta=0.05, seed=7), "low"),
+    # chip-scale instances (one TPU v5e, a few GiB of its 16 GB HBM):
+    # grid2d_8m stays below the 2^24 unit-weight bound of fp32 exactness
+    # (DESIGN.md §1); rmat_21 is Graph500's Kronecker generator at scale 21
+    # (a=.57, b=.19, c=.19, edge factor 16 — 33.5M edges before dedup)
+    "grid2d_8m": (grid2d, dict(nx=2048, ny=4096), "low"),
+    # the mesh that one chip refines within the smoke run's time limit
+    # (chip_smoke.py; grid2d_8m's refinement is gather-bound there, PERF.md)
+    "grid2d_256k": (grid2d, dict(nx=512, ny=512), "low"),
+    "rmat_21": (rmat, dict(scale=21, edge_factor=16, seed=5), "high"),
     # tiny smoke instances — seconds-scale CLI subprocess tests
     # (tests/test_kill_resume.py) and quick local runs, not benchmark cells
     "grid2d_1k": (grid2d, dict(nx=32, ny=32), "low"),

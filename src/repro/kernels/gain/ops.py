@@ -1,9 +1,9 @@
 """jit'd public wrapper around the gain scoreboard kernel.
 
-On TPU this lowers to the Pallas kernel; on CPU (this container) the kernel
-body executes in interpret mode — same code path, Python-evaluated — so the
-BlockSpec tiling is validated for correctness here and for performance via
-the dry-run's lowered HLO.
+On TPU this lowers to the compiled Mosaic kernel; on CPU the kernel body
+executes in interpret mode — same code path, Python-evaluated — so the
+BlockSpec tiling is validated for correctness off-chip
+(``tests/test_tpu_compile.py`` compiles it for a described v5e).
 
 This is the *standalone* wrapper (whole-graph padded adjacency, labels
 gathered here) used by the kernel tests and benchmarks; the production hot
@@ -20,6 +20,7 @@ from typing import TYPE_CHECKING
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.gain.kernel import LANE, gain_scoreboard_pallas, round_up
 
 if TYPE_CHECKING:  # runtime import is deferred: breaks the core↔refine cycle
@@ -62,8 +63,7 @@ def gain_scoreboard(
     """
     from repro.core.graph import PAD
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = default_interpret(interpret)
     n_pad = nbr.shape[0]
     n = labels.shape[0]
 
@@ -78,7 +78,7 @@ def gain_scoreboard(
     nw_p = jnp.pad(nw, (0, n_pad - n))
 
     own, gain, tgt = gain_scoreboard_pallas(
-        nbr_lab, nbr_w, lab_p, nw_p, cap,
+        nbr_lab.T, nbr_w.T, lab_p, nw_p, cap,
         tile_n=tile_n, deg_chunk=deg_chunk, interpret=interpret,
     )
-    return own[:n, 0], gain[:n, 0], tgt[:n, 0]
+    return own[:n], gain[:n], tgt[:n]

@@ -1,15 +1,10 @@
-# Fused halo move-application / label-relayout kernels (DESIGN.md §5).
+# Fused halo move-application kernel (DESIGN.md §5).
 from repro.kernels.halo.ops import (  # noqa: F401
     HALO_MAX_CAND,
-    HALO_MAX_N,
     apply_moves,
-    fused_apply,
-    relayout,
     resolve_halo,
 )
 from repro.kernels.halo.ref import (  # noqa: F401
     halo_apply_range_ref,
     halo_apply_ref,
-    halo_fused_ref,
-    halo_gather_ref,
 )

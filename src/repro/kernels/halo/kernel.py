@@ -1,43 +1,34 @@
-"""Pallas TPU kernel: fused halo move application + label relayout.
+"""Pallas TPU kernel: halo move application.
 
 The halo refinement hot path ends every greedy-rebalance epoch with
-"apply the replayed global move list to my owned slots" and brackets every
-level with "permute labels between block layout and the interface-first
-halo layout".  The XLA rendering is a chain of gathers/scatters
-(``HaloComm.apply_moves``: range test → ``inv_perm`` gather → scatter;
-``block_labels_to_halo``/``from_halo``: ``take_along_axis``), each a
-separate HBM round trip.
+"apply the replayed global move list to my owned slots".  The XLA
+rendering (``HaloComm.apply_moves``) is a chain — range test →
+``inv_perm`` gather → scatter — each a separate HBM round trip.
 
-This kernel replaces the chain with VMEM-resident passes:
+``halo_apply_pallas`` replaces the chain with one VMEM-resident pass, a
+dense gid-compare: labels and per-slot global ids stream through VMEM in
+(TILE_N, 1) tiles while the whole move list (ncand ≤ a few thousand ids)
+stays resident as (1, C) lane vectors; CAND_CHUNK candidates are compared
+per step.  Slot i takes ``tgts[j]`` iff ``moved[j] ∧ tids[j] == gid[i]``.
+This is *equivalent* to the range-test + inverse-permutation formulation
+because (a) a non-owned slot carries ``gid = PAD`` which matches no real
+move id, and (b) the engine's move list contains each global id at most
+once (candidates are per-owned-vertex and every vertex is owned by exactly
+one PE), so the max-select over matches returns the unique target.
 
-  * **move application** (``halo_apply_pallas``) — a dense gid-compare:
-    labels and per-slot global ids stream through VMEM in (TILE_N, 1)
-    tiles while the whole move list (ncand ≤ a few thousand ids) stays
-    resident as (1, C) lane vectors; CAND_CHUNK candidates are compared
-    per step.  Slot i takes ``tgts[j]`` iff ``moved[j] ∧ tids[j] ==
-    gid[i]``.  This is *equivalent* to the range-test + inverse-
-    permutation formulation because (a) a non-owned slot carries
-    ``gid = PAD`` which matches no real move id, and (b) the engine's
-    move list contains each global id at most once (candidates are
-    per-owned-vertex and every vertex is owned by exactly one PE), so
-    the max-select over matches returns the unique target.
-  * **relayout** (``halo_gather_pallas``) — the permutation gather
-    ``out[i] = x[perm[i]]`` with ``x`` VMEM-resident and the permutation
-    streamed in tiles.  Both layout directions are gathers
-    (``from_halo`` through ``inv_perm``).
-  * **fused entry** (``halo_fused_pallas``) — relayout-in + move
-    application in ONE ``pallas_call``: block-layout labels in, updated
-    halo-layout labels out, no intermediate HBM round trip.
+The halo↔block label *relayout* is not a kernel: it is a whole-shard
+permutation gather, and Mosaic gathers only within one (8, 128) vreg
+("Multiple source vregs along gather dimension"), so the level program
+renders it with XLA's gather on every backend (DESIGN.md §5).
 
-All outputs are int32 — the kernels move labels, never weights — so
+The output is int32 — the kernel moves labels, never weights — so
 "bit-identical" here is exact integer equality, and the jnp references in
 ``ref.py`` are the oracles the determinism matrix pins against.
 
 VMEM budget per program instance (TILE_N=256, C≤8192, int32): label/gid
-tiles 2 KiB each, move list 3·32 KiB, compare matrix TILE_N×CAND_CHUNK×4 =
-128 KiB (CAND_CHUNK=128) — far inside the envelope; the gather kernels
-additionally hold the whole (1, N) source vector, which bounds them to
-n_local ≤ ~1M (``ops.HALO_MAX_N``).
+tiles 2 KiB each (8 KiB lane-padded), move list 3·32 KiB (×8 sublane
+padding), compare matrix TILE_N×CAND_CHUNK×4 = 128 KiB (CAND_CHUNK=128) —
+far inside the envelope and independent of n_local.
 """
 
 from __future__ import annotations
@@ -54,12 +45,11 @@ I32_MIN = jnp.iinfo(jnp.int32).min
 PAD_I32 = jnp.iinfo(jnp.int32).max  # == repro.core.graph.PAD (pinned in tests)
 
 
-def _apply_body(gid, tid_ref, tgt_ref, mov_ref, init, *, cand_chunk: int):
-    """Shared fori_loop over candidate chunks: dense gid-compare select.
-
-    ``gid`` is the (TILE_N, 1) per-slot global id, ``init`` the (TILE_N, 1)
-    incumbent labels; returns labels with matched moves applied.
-    """
+def _apply_kernel(lab_ref, gid_ref, tid_ref, tgt_ref, mov_ref, out_ref, *,
+                  cand_chunk: int):
+    """fori_loop over candidate chunks: dense gid-compare select of the
+    (TILE_N, 1) per-slot global ids against the resident move list."""
+    gid = gid_ref[:, :1]
     c_tot = tid_ref.shape[1]
 
     def body(c, lab):
@@ -72,28 +62,8 @@ def _apply_body(gid, tid_ref, tgt_ref, mov_ref, init, *, cand_chunk: int):
         val = jnp.max(jnp.where(m, g, I32_MIN), axis=1, keepdims=True)
         return jnp.where(hit, val, lab)
 
-    return jax.lax.fori_loop(0, c_tot // cand_chunk, body, init)
-
-
-def _apply_kernel(lab_ref, gid_ref, tid_ref, tgt_ref, mov_ref, out_ref, *,
-                  cand_chunk: int):
-    out_ref[:, :] = _apply_body(
-        gid_ref[:, :1], tid_ref, tgt_ref, mov_ref, lab_ref[:, :1],
-        cand_chunk=cand_chunk)
-
-
-def _gather_kernel(x_ref, perm_ref, out_ref):
-    x = x_ref[0, :]            # whole (N,) source vector, VMEM-resident
-    out_ref[:, :] = x[perm_ref[:, 0]][:, None]
-
-
-def _fused_kernel(lab_ref, perm_ref, gid_ref, tid_ref, tgt_ref, mov_ref,
-                  out_ref, *, cand_chunk: int):
-    x = lab_ref[0, :]
-    base = x[perm_ref[:, 0]][:, None]          # relayout-in (block → halo)
-    out_ref[:, :] = _apply_body(
-        gid_ref[:, :1], tid_ref, tgt_ref, mov_ref, base,
-        cand_chunk=cand_chunk)
+    out_ref[:, :] = jax.lax.fori_loop(0, c_tot // cand_chunk, body,
+                                      lab_ref[:, :1])
 
 
 def _pad_moves(tids, tgts, moved, cand_chunk: int):
@@ -117,6 +87,10 @@ def halo_apply_pallas(labels, gid, tids, tgts, moved, *, tile_n: int = 256,
     (``PAD`` on non-owned slots); ``tids``/``tgts``/``moved`` the (c,)
     gathered move records.  Returns the (n,) updated labels.
     """
+    if not interpret and (tile_n % 8 or cand_chunk % 128):
+        raise ValueError(f"compiled halo kernel needs tile_n % 8 == 0 and "
+                         f"cand_chunk % 128 == 0, got tile_n={tile_n}, "
+                         f"cand_chunk={cand_chunk}")
     n = labels.shape[0]
     n_pad = round_up(max(n, 1), tile_n)
     lab = jnp.pad(labels.astype(jnp.int32), (0, n_pad - n))
@@ -141,67 +115,4 @@ def halo_apply_pallas(labels, gid, tids, tgts, moved, *, tile_n: int = 256,
         out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
         interpret=interpret,
     )(lab[:, None], gid_p[:, None], tid2, tgt2, mov2)
-    return out[:n, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def halo_gather_pallas(x, perm, *, tile_n: int = 256, interpret: bool = False):
-    """Permutation gather ``out[i] = x[perm[i]]`` (label relayout).
-
-    ``x`` is kept whole in VMEM; ``perm`` streams in (tile_n, 1) tiles.
-    Out-of-range permutation entries are the caller's bug (the layout
-    permutations are total by construction).
-    """
-    n = x.shape[0]
-    n_pad = round_up(max(n, 1), tile_n)
-    x_p = jnp.pad(x.astype(jnp.int32), (0, n_pad - n))
-    perm_p = jnp.pad(perm.astype(jnp.int32), (0, n_pad - n))
-
-    out = pl.pallas_call(
-        _gather_kernel,
-        grid=(n_pad // tile_n,),
-        in_specs=[
-            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),
-            pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-        interpret=interpret,
-    )(x_p[None, :], perm_p[:, None])
-    return out[:n, 0]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("tile_n", "cand_chunk", "interpret"))
-def halo_fused_pallas(lab_block, perm_loc, gid, tids, tgts, moved, *,
-                      tile_n: int = 256, cand_chunk: int = 128,
-                      interpret: bool = False):
-    """Relayout-in + move application in one pass: block-layout labels →
-    updated halo-layout labels, no intermediate HBM round trip."""
-    n = lab_block.shape[0]
-    n_pad = round_up(max(n, 1), tile_n)
-    lab = jnp.pad(lab_block.astype(jnp.int32), (0, n_pad - n))
-    perm_p = jnp.pad(perm_loc.astype(jnp.int32), (0, n_pad - n))
-    gid_p = jnp.pad(gid.astype(jnp.int32), (0, n_pad - n),
-                    constant_values=int(PAD_I32))
-    tid2, tgt2, mov2 = _pad_moves(tids, tgts, moved, cand_chunk)
-    c_pad = tid2.shape[1]
-
-    row = lambda i: (i, 0)
-    whole = lambda i: (0, 0)
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, cand_chunk=cand_chunk),
-        grid=(n_pad // tile_n,),
-        in_specs=[
-            pl.BlockSpec((1, n_pad), whole),
-            pl.BlockSpec((tile_n, 1), row),
-            pl.BlockSpec((tile_n, 1), row),
-            pl.BlockSpec((1, c_pad), whole),
-            pl.BlockSpec((1, c_pad), whole),
-            pl.BlockSpec((1, c_pad), whole),
-        ],
-        out_specs=pl.BlockSpec((tile_n, 1), row),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-        interpret=interpret,
-    )(lab[None, :], perm_p[:, None], gid_p[:, None], tid2, tgt2, mov2)
     return out[:n, 0]

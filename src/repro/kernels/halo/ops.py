@@ -1,56 +1,37 @@
-"""Public dispatch for the halo move/relayout kernels (DESIGN.md §5).
+"""Public dispatch for the halo move-application kernel (DESIGN.md §5).
 
 Mirrors the gain scoreboard's contract: ``resolve_halo`` applies the VMEM
 envelope fallback rule (requests outside it silently stream through the
-jnp path — the partition is bit-identical either way), the wrappers run in
+jnp path — the partition is bit-identical either way), the wrapper runs in
 interpret mode off-TPU, and tile parameters left ``None`` are resolved
 from the committed autotune table (``repro.kernels.tune``) at trace time.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.halo.kernel import (
-    halo_apply_pallas,
-    halo_fused_pallas,
-    halo_gather_pallas,
-)
+from repro.kernels import default_interpret
+from repro.kernels.halo.kernel import halo_apply_pallas
 
 # VMEM envelope (DESIGN.md §5): the move list lives whole in VMEM as (1, C)
-# lane vectors, the gather kernels additionally hold the whole (1, N)
-# source vector (~4 MiB at the bound).
+# lane vectors; the labels stream in tiles, so n_local is unbounded.
 HALO_MAX_CAND = 8192
-HALO_MAX_N = 1 << 20
 
 
-def resolve_halo(kind: str, n_local: int, ncand: int) -> str:
-    """Apply the fallback rule: the fused halo kernels serve move lists of
-    ≤ ``HALO_MAX_CAND`` candidates on shards of ≤ ``HALO_MAX_N`` slots;
-    anything larger keeps the XLA gather/scatter path.  ``kind`` is the
-    same backend switch as the gain kernel ("jnp" / "pallas" / "auto")."""
+def resolve_halo(kind: str, ncand: int) -> str:
+    """Apply the fallback rule: the halo kernel serves move lists of
+    ≤ ``HALO_MAX_CAND`` candidates; anything larger keeps the XLA
+    gather/scatter path.  ``kind`` is the same backend switch as the gain
+    kernel ("jnp" / "pallas" / "auto")."""
     if kind == "auto":
         kind = "pallas"
     if kind not in ("jnp", "pallas"):
         raise ValueError(
             f"halo kernel backend must be 'jnp', 'pallas' or 'auto', got {kind!r}")
-    if kind == "pallas" and (ncand > HALO_MAX_CAND or n_local > HALO_MAX_N):
+    if kind == "pallas" and ncand > HALO_MAX_CAND:
         return "jnp"
     return kind
-
-
-def _interpret(interpret: bool | None) -> bool:
-    return jax.default_backend() != "tpu" if interpret is None else interpret
-
-
-def _tiles(n_local: int, ncand: int, tile_n: int | None,
-           cand_chunk: int | None):
-    from repro.kernels.tune import lookup
-
-    cfg = lookup("halo", n=n_local, d=ncand, k=1)
-    return (tile_n if tile_n is not None else cfg["tile_n"],
-            cand_chunk if cand_chunk is not None else cfg["cand_chunk"])
 
 
 def apply_moves(labels, gid, tids, tgts, moved, *, tile_n: int | None = None,
@@ -61,31 +42,11 @@ def apply_moves(labels, gid, tids, tgts, moved, *, tile_n: int | None = None,
     backend is active; shapes need no pre-padding (the wrapper pads to the
     tile grid and slices back).
     """
-    tile_n, cand_chunk = _tiles(labels.shape[0], tids.shape[0], tile_n,
-                                cand_chunk)
-    return halo_apply_pallas(labels, gid, tids, tgts,
-                             moved.astype(jnp.int32), tile_n=tile_n,
-                             cand_chunk=cand_chunk,
-                             interpret=_interpret(interpret))
+    from repro.kernels.tune import lookup
 
-
-def relayout(x, perm, *, tile_n: int | None = None,
-             interpret: bool | None = None):
-    """Label relayout ``out[i] = x[perm[i]]`` — both halo↔block directions
-    (``from_halo`` gathers through ``inv_perm``)."""
-    tile_n, _ = _tiles(x.shape[0], 0, tile_n, None)
-    return halo_gather_pallas(x, perm, tile_n=tile_n,
-                              interpret=_interpret(interpret))
-
-
-def fused_apply(lab_block, perm_loc, gid, tids, tgts, moved, *,
-                tile_n: int | None = None, cand_chunk: int | None = None,
-                interpret: bool | None = None):
-    """Relayout-in + move application in one ``pallas_call`` (the
-    VMEM-resident composition benchmarked by ``kernel_bench.py``)."""
-    tile_n, cand_chunk = _tiles(lab_block.shape[0], tids.shape[0], tile_n,
-                                cand_chunk)
-    return halo_fused_pallas(lab_block, perm_loc, gid, tids, tgts,
-                             moved.astype(jnp.int32), tile_n=tile_n,
-                             cand_chunk=cand_chunk,
-                             interpret=_interpret(interpret))
+    cfg = lookup("halo", n=labels.shape[0], d=tids.shape[0], k=1)
+    return halo_apply_pallas(
+        labels, gid, tids, tgts, moved.astype(jnp.int32),
+        tile_n=cfg["tile_n"] if tile_n is None else tile_n,
+        cand_chunk=cfg["cand_chunk"] if cand_chunk is None else cand_chunk,
+        interpret=default_interpret(interpret))

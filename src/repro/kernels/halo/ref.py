@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the halo move/relayout kernels.
+"""Pure-jnp oracles for the halo move-application kernel.
 
 Two independent references pin the move-application kernel:
 
@@ -43,14 +43,3 @@ def halo_apply_range_ref(labels, tids, tgts, moved, *, gstart, n_local,
     ok = inb & owned[slot]
     idx = jnp.where(ok, slot, n_local)
     return labels.at[idx].set(tgts, mode="drop")
-
-
-def halo_gather_ref(x, perm):
-    """Permutation-gather oracle (the ``take_along_axis`` relayout)."""
-    return x[perm].astype(jnp.int32)
-
-
-def halo_fused_ref(lab_block, perm_loc, gid, tids, tgts, moved):
-    """Relayout-in + move application, composed from the oracles."""
-    return halo_apply_ref(halo_gather_ref(lab_block, perm_loc), gid,
-                          tids, tgts, moved)

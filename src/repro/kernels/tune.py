@@ -1,4 +1,4 @@
-"""Tile autotuner for the Pallas kernels (gain scoreboard + halo fused ops).
+"""Tile autotuner for the Pallas kernels (gain scoreboard + halo move application).
 
 The kernels' tile parameters (TILE_N, DEG_CHUNK, CAND_CHUNK) never change
 *results* — padding rows/columns are inert by construction and the tile
@@ -55,10 +55,11 @@ _CACHE: dict[str, dict] = {}
 
 def backend_name(interpret: bool | None = None) -> str:
     """The backend axis of the bucket key: compiled Mosaic vs interpret."""
+    from repro.kernels import default_interpret
+
     on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = not on_tpu
-    return "tpu" if (on_tpu and not interpret) else "interpret"
+    return "tpu" if (on_tpu and not default_interpret(interpret)) \
+        else "interpret"
 
 
 def _pow2_bucket(x: int) -> int:

@@ -42,6 +42,9 @@ from repro.roofline.analysis import (
     scan_flops_correction,
 )
 
+# the compiled cells run on forced CPU devices and model this chip's peaks
+TARGET_HW = "v5e"
+
 # per-arch launcher policy: optimizer + memory knobs for the big models
 ARCH_POLICY = {
     "deepseek_v3_671b": dict(optimizer="adafactor", zero_over_pod=True),
@@ -194,7 +197,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, lower_only: bool = Fal
         from repro.models.transformer import segments as _segments
 
         body_scale = float(max(r for _, r in _segments(cfg)))
-    roof = analyze_compiled(compiled, n_chips, mf, body_scale=body_scale)
+    roof = analyze_compiled(compiled, n_chips, mf, hw=TARGET_HW,
+                            body_scale=body_scale)
     # inner-scan flop remainder (analytic, global → per-device)
     corr = scan_flops_correction(cfg, shape) / n_chips
     if not unroll:
@@ -336,7 +340,7 @@ def run_partitioner_cell(multi_pod: bool, n_local: int = 1 << 18,
     with pe_mesh:
         lowered = f.lower(*args)
         compiled = lowered.compile()
-    roof = analyze_compiled(compiled, Pn, model_flops=0.0)
+    roof = analyze_compiled(compiled, Pn, model_flops=0.0, hw=TARGET_HW)
     print(compiled.memory_analysis())
     name = "paper_partitioner_jet" + ("+halo" if halo else "")
     return {
@@ -388,7 +392,8 @@ def run_ring_decode_cell(multi_pod: bool = False):
     t0 = time.time()
     with mesh:
         compiled = f.lower(*args).compile()
-    roof = analyze_compiled(compiled, mesh.devices.size, model_flops=0.0)
+    roof = analyze_compiled(compiled, mesh.devices.size, model_flops=0.0,
+                            hw=TARGET_HW)
     n_layers = 40
     per_layer = sum(roof.coll_bytes.values())
     rec = {
@@ -447,7 +452,7 @@ def run_moe_ep_cell(multi_pod: bool = False, capacity_factor: float = 1.25):
     t0 = time.time()
     with mesh:
         compiled = f.lower(*args).compile()
-    roof = analyze_compiled(compiled, n_chips, model_flops=0.0)
+    roof = analyze_compiled(compiled, n_chips, model_flops=0.0, hw=TARGET_HW)
     n_moe_layers = cfg.n_layers - cfg.n_dense_layers
     per_layer = sum(roof.coll_bytes.values())
     step_coll = per_layer * n_moe_layers * 3.0  # fwd + ~2x bwd

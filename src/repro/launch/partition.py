@@ -6,12 +6,27 @@
 
 import argparse
 import json
+import os
 import time
 
 from repro.core import PartitionConfig, partition
 from repro.graphs import BENCHMARK_SET, generate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.refine.schedule import SCHEDULE_ALIASES, SCHEDULES, resolve_schedule
 from repro.refine.variants import ALIASES, registered_variants
+
+
+def _force_host_devices(p: int) -> None:
+    """On the CPU platform, simulate ``p`` PEs with forced host devices:
+    appends the flag to ``XLA_FLAGS`` (keeping the caller's flags, and a
+    device count the caller already forced).  Must run before the backend
+    initialises; an accelerator platform keeps its real devices."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "--xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={p}".strip())
 
 
 def main():
@@ -32,7 +47,8 @@ def main():
                     help="stop coarsening at this many vertices "
                          "(default: max(512, 16k))")
     ap.add_argument("--distributed", type=int, default=0,
-                    help="run refinement under shard_map with P forced host devices")
+                    help="run the sharded V-cycle over P devices (on the "
+                         "CPU platform: P forced host devices)")
     ap.add_argument("--ingest", default=None, metavar="MANIFEST",
                     help="out-of-core input: build the device shards from a "
                          "chunked edge manifest (repro.graphs.ingest) instead "
@@ -79,6 +95,9 @@ def main():
                          "submissions in real time and enforces "
                          "--serve-deadline-us against monotonic time")
     args = ap.parse_args()
+    if args.distributed:
+        _force_host_devices(args.distributed)
+    enable_compile_cache()
     if sum(map(bool, (args.batch, args.distributed,
                       args.serve_trace))) > 1:
         ap.error("--batch, --distributed and --serve-trace are "
@@ -189,12 +208,14 @@ def main():
                    batch=args.batch, sec=round(sec, 2),
                    graphs_per_sec=round(args.batch / sec, 3))
     elif args.distributed:
-        import os
+        import jax
 
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.distributed}"
-        )
         from repro.distributed import dpartition
+
+        if args.distributed > jax.device_count():
+            ap.error(f"--distributed {args.distributed} needs that many "
+                     f"devices; this {jax.default_backend()} backend has "
+                     f"{jax.device_count()}")
 
         if args.ingest:
             from repro.graphs import ingest_sharded, load_manifest

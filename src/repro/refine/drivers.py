@@ -200,7 +200,7 @@ def _sharded_level_fn(mesh, k, n_local, n_real, patience, max_inner,
     var = resolve_variant(variant)
     kind = "lp" if var.mode == "lp" else "sharded"
 
-    def per_pe(src, dst, ew, nw, owned, gstart, labels, key, lmax, taus):
+    def refine_allgather(src, dst, ew, nw, owned, gstart, labels, key, lmax, taus):
         _count_trace(kind)
         ev = _sharded_edge_view(src[0], dst[0], ew[0], nw[0], owned[0],
                                 n_local)
@@ -216,7 +216,7 @@ def _sharded_level_fn(mesh, k, n_local, n_real, patience, max_inner,
 
     sh = P("pe", None)
     return kind, jax.jit(shard_map(
-        per_pe, mesh=mesh,
+        refine_allgather, mesh=mesh,
         in_specs=(sh, sh, sh, sh, sh, P("pe"), sh, P(), P(), P()),
         out_specs=sh,
     ))
@@ -230,6 +230,8 @@ def make_refine_level_sharded(mesh, sg, k, *, rounds_taus, patience=12,
     Returns ``run(lab_sh, key, lmax) -> lab_sh`` — one dispatch per call.
     ``rounds_taus`` is the temperature vector; ``variant`` names the
     registered move-generation rule (lp-mode variants ignore the taus).
+    ``run.backends`` names the backends the fallback rules resolved
+    (``{"gain": ..., "halo": None}`` — this protocol has no halo kernel).
     """
     from repro.distributed.dgraph import owned_mask
 
@@ -248,6 +250,7 @@ def make_refine_level_sharded(mesh, sg, k, *, rounds_taus, patience=12,
         return fn(sg.src, sg.dst, sg.ew, sg.nw, owned, sg.vtx_start, lab_sh,
                   key, jnp.float32(lmax), taus)
 
+    run.backends = {"gain": gain_kind, "halo": None}
     return run
 
 
@@ -462,11 +465,14 @@ def _halo_level_fn(mesh, k, n_local, n_real, n_pe, h_local, patience,
     them to the interface-first layout in-trace (a gather through
     ``perm_loc``), refines, and permutes back through ``inv_perm`` — the
     layout conversions compile into the one level dispatch instead of
-    standing alone as separate ``take_along_axis`` dispatches."""
+    standing alone as separate ``take_along_axis`` dispatches.  Both
+    directions are XLA gathers on every backend (the old scatter rendering
+    of ``block_labels_from_halo`` is the same map since the permutations
+    are total); Mosaic cannot express a whole-shard gather (DESIGN.md §5)."""
     var = resolve_variant(variant)
 
-    def per_pe(src, dst_code, head_gid, ew, nw, my_gid, owned, inv_perm,
-               perm_loc, gstart, labels, key, lmax, taus):
+    def refine_halo(src, dst_code, head_gid, ew, nw, my_gid, owned, inv_perm,
+                    perm_loc, gstart, labels, key, lmax, taus):
         _count_trace("halo")
         ev = halo_edge_view(src[0], dst_code[0], head_gid[0], ew[0], nw[0],
                             my_gid[0], owned[0])
@@ -476,7 +482,7 @@ def _halo_level_fn(mesh, k, n_local, n_real, n_pe, h_local, patience,
         gb = make_gain(gain_kind, ev, k, max_deg, interpret)
         lab = labels[0]
         if relayout:
-            lab = _halo_relayout(lab, perm_loc[0], halo_kind, interpret)
+            lab = lab[perm_loc[0]]
         if var.mode == "lp":
             out = engine.lp_level(cm, gb, ev, lab, key, lmax, k)
         else:
@@ -484,30 +490,16 @@ def _halo_level_fn(mesh, k, n_local, n_real, n_pe, h_local, patience,
                                       k, patience, max_inner,
                                       move_fn=var.move)
         if relayout:
-            out = _halo_relayout(out, inv_perm[0], halo_kind, interpret)
+            out = out[inv_perm[0]]
         return out[None]
 
     sh = P("pe", None)
     return jax.jit(shard_map(
-        per_pe, mesh=mesh,
+        refine_halo, mesh=mesh,
         in_specs=(sh, sh, sh, sh, sh, sh, sh, sh, sh, P("pe"), sh, P(), P(),
                   P()),
         out_specs=sh,
     ))
-
-
-def _halo_relayout(lab, perm, halo_kind: str, interpret):
-    """One direction of the per-PE label relayout, ``out[i] = lab[perm[i]]``
-    — both directions are gathers (block → halo through ``perm_loc``,
-    halo → block through ``inv_perm``; the old scatter formulation of
-    ``block_labels_from_halo`` is the same map since the permutations are
-    total).  Values are identical under either backend — a gather moves
-    labels, it computes nothing."""
-    if halo_kind == "pallas":
-        from repro.kernels.halo import relayout
-
-        return relayout(lab, perm, interpret=interpret)
-    return lab[perm]
 
 
 def make_refine_level_halo(mesh, hsg, k, *, rounds_taus, patience=12,
@@ -531,6 +523,8 @@ def make_refine_level_halo(mesh, hsg, k, *, rounds_taus, patience=12,
     ``relayout=True`` makes ``run`` take and return *block-layout* labels,
     fusing the halo↔block conversions into the level program (the sharded
     V-cycle's setting); the default keeps the halo-layout interface.
+    ``run.backends`` names the gain and halo backends the fallback rules
+    resolved.
     """
     from repro.kernels.halo import resolve_halo
 
@@ -538,7 +532,7 @@ def make_refine_level_halo(mesh, hsg, k, *, rounds_taus, patience=12,
     max_deg = (sharded_max_deg(hsg.src, hsg.head_gid, hsg.n_local)
                if _need_max_deg(gain) else None)
     gain_kind = resolve_gain(gain, k, max_deg)
-    halo_kind = resolve_halo(gain, hsg.n_local, hsg.P * engine.GREEDY_NCAND)
+    halo_kind = resolve_halo(gain, hsg.P * engine.GREEDY_NCAND)
     fn = _halo_level_fn(
         mesh, k, hsg.n_local, hsg.n_real, hsg.P, hsg.h_local, patience,
         max_inner, gain_kind, max_deg if gain_kind == "pallas" else None,
@@ -551,4 +545,5 @@ def make_refine_level_halo(mesh, hsg, k, *, rounds_taus, patience=12,
                   hsg.my_gid, hsg.owned, hsg.inv_perm, hsg.perm_loc,
                   hsg.gstart, lab_sh, key, jnp.float32(lmax), taus)
 
+    run.backends = {"gain": gain_kind, "halo": halo_kind}
     return run

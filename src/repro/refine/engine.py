@@ -193,6 +193,41 @@ def prob_pass(cm, gb, ev: EdgeView, labels, key, lmax, k: int):
 # Greedy rebalancer (dKaMinPar Ref. [9]) — two-stage top-k + replay
 # --------------------------------------------------------------------------
 
+_IMAX = jnp.iinfo(jnp.int32).max
+
+
+def _lex_best(a, b):
+    """Reduction of (score, tid, index) triples to the lexicographic best:
+    score desc, then tid asc, then index asc — associative and commutative
+    on NaN-free scores, so any reduction tree gives the same triple."""
+    sa, ta, ia = a
+    sb, tb, ib = b
+    take_b = (sb > sa) | ((sb == sa) & ((tb < ta) | ((tb == ta) & (ib < ia))))
+    return (jnp.where(take_b, sb, sa), jnp.where(take_b, tb, ta),
+            jnp.where(take_b, ib, ia))
+
+
+def top_candidates(score, tid, n_sel: int):
+    """Slot indices of the ``n_sel`` best slots in (score desc, tid asc,
+    slot asc) order — ``jnp.lexsort((tid, -score))[:n_sel]`` for NaN-free
+    scores, computed as ``n_sel`` lexicographic max-reductions instead of a
+    sort of all n_local slots: a TPU sort of 2^20 elements takes ~40 s to
+    compile per level program, a reduction well under one."""
+    idx = jnp.arange(score.shape[0], dtype=jnp.int32)
+
+    def body(j, carry):
+        s, t, ix, sel = carry
+        _, _, i = jax.lax.reduce((s, t, ix), (NEG, _IMAX, _IMAX), _lex_best,
+                                 (0,))
+        # a taken slot becomes (-inf, IMAX, IMAX): it loses every tie
+        return (s.at[i].set(NEG), t.at[i].set(_IMAX), ix.at[i].set(_IMAX),
+                sel.at[j].set(i))
+
+    init = (score.astype(jnp.float32), tid.astype(jnp.int32), idx,
+            jnp.zeros((n_sel,), jnp.int32))
+    return jax.lax.fori_loop(0, n_sel, body, init)[3]
+
+
 def greedy_epoch(cm, gb, ev: EdgeView, labels, lmax, k: int,
                  ncand: int = GREEDY_NCAND):
     """One centrally coordinated epoch.
@@ -217,7 +252,7 @@ def greedy_epoch(cm, gb, ev: EdgeView, labels, lmax, k: int,
     # slot position: halo slots are permuted interface-first, so positional
     # top_k stability would break the cross-backend determinism contract
     nc_loc = min(ncand, ev.n_local)
-    idx = jnp.lexsort((ev.my_tid, -score))[:nc_loc]
+    idx = top_candidates(score, ev.my_tid, nc_loc)
 
     rec_s = cm.gather(score[idx])
     rec_tid = cm.gather(ev.my_tid[idx])

@@ -29,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.gain.kernel import LANE, gain_scoreboard_pallas
 from repro.kernels.gain.kernel import round_up as _round_up
 
@@ -90,8 +91,9 @@ class PallasGain:
     """Scoreboard-kernel gain backend.
 
     Construction (once per level, loop-invariant inside the fused level
-    program) builds the padded adjacency in *edge-slot* coordinates:
-    ``eslot[v, r]`` is the edge index of v's r-th neighbour (m = padding).
+    program) builds the neighbour-major padded adjacency in *edge-slot*
+    coordinates: ``eslot[r, v]`` is the edge index of v's r-th neighbour
+    (m = padding).
     Per round the head labels are produced by the comm backend's per-edge
     lookup and gathered through ``eslot`` — so one padded adjacency serves
     every round and every comm backend.
@@ -102,9 +104,7 @@ class PallasGain:
     def __init__(self, ev, k: int, max_deg: int, tile_n: int | None = None,
                  deg_chunk: int | None = None, interpret: bool | None = None):
         self.k = k
-        self.interpret = (
-            jax.default_backend() != "tpu" if interpret is None else interpret
-        )
+        self.interpret = default_interpret(interpret)
         n_loc = ev.n_local
         # tile parameters left None resolve from the committed autotune
         # table (kernels/tune.py) — a trace-time, per-process-deterministic
@@ -124,27 +124,26 @@ class PallasGain:
         d = _round_up(max(int(max_deg), 1), deg_chunk)
         n_pad = _round_up(max(n_loc, 1), tile_n)
 
-        # rank of each live edge within its row (rows need not be contiguous
-        # in the slot array: recover CSR order with one stable sort)
-        skey = jnp.where(ev.live, ev.src, n_loc).astype(jnp.int32)
-        order = jnp.argsort(skey)
-        sk = skey[order]
-        starts = jnp.searchsorted(sk, jnp.arange(n_loc, dtype=jnp.int32),
-                                  side="left")
-        rank_sorted = (
-            jnp.arange(m, dtype=jnp.int32)
-            - starts[jnp.clip(sk, 0, max(n_loc - 1, 0))].astype(jnp.int32)
-        )
-        rank = jnp.zeros((m,), jnp.int32).at[order].set(rank_sorted)
+        # rank of each live edge within its row.  Every layout stores a
+        # row's live edges as one run of the live slots (CSR order, row ids
+        # possibly relabelled — the halo layout), so the rank is the count
+        # of live slots since the row's first one: two cumulative counts
+        # and a segment-min, no sort (a TPU sort of m slots costs tens of
+        # seconds of compile per level program)
+        live_i = ev.live.astype(jnp.int32)
+        before = jnp.cumsum(live_i) - live_i      # live slots before e
+        row = jnp.where(ev.live, ev.src, n_loc).astype(jnp.int32)
+        first = jax.ops.segment_min(before, row, num_segments=n_loc + 1)
+        rank = before - first[row]
 
         ok = ev.live & (rank < d)
         rows = jnp.where(ok, ev.src, n_pad)     # pads routed out of bounds
         cols = jnp.where(ok, rank, 0)
         slots = jnp.arange(m, dtype=jnp.int32)
-        self.eslot = jnp.full((n_pad, d), m, jnp.int32).at[rows, cols].set(
+        self.eslot = jnp.full((d, n_pad), m, jnp.int32).at[cols, rows].set(
             jnp.where(ok, slots, m), mode="drop"
         )
-        self.nbr_w = jnp.zeros((n_pad, d), jnp.float32).at[rows, cols].set(
+        self.nbr_w = jnp.zeros((d, n_pad), jnp.float32).at[cols, rows].set(
             jnp.where(ok, ev.ew, 0.0), mode="drop"
         )
         self.n_loc = n_loc
@@ -172,7 +171,7 @@ class PallasGain:
             tile_n=self.tile_n, deg_chunk=self.deg_chunk,
             interpret=self.interpret,
         )
-        return own[: self.n_loc, 0], gain[: self.n_loc, 0], tgt[: self.n_loc, 0]
+        return own[: self.n_loc], gain[: self.n_loc], tgt[: self.n_loc]
 
 
 def make_gain(kind: str, ev, k: int, max_deg: int | None = None,

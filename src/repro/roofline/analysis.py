@@ -18,8 +18,8 @@ collective-permute, weighted by the ring-algorithm wire factor:
     all-to-all      1.0
     collective-permute 1.0
 
-Hardware constants default to TPU v5e (the brief's target): 197 bf16
-TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI.  Every entry point takes an
+Hardware constants default to those of the attached device (TPU v5e: 197
+bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI).  Every entry point takes an
 ``hw=`` override — a preset name from :data:`HW_PRESETS` or a dict with
 the three ``peak_flops``/``hbm_bw``/``ici_bw`` keys, validated eagerly by
 :func:`resolve_hw` (a missing key or non-positive value fails with the
@@ -58,12 +58,29 @@ HW_PRESETS = {"v5e": HW_V5E, "v5p": HW_V5P, "cpu": HW_CPU}
 HW_KEYS = ("peak_flops", "hbm_bw", "ici_bw")
 
 
+# jax ``device_kind`` → preset: what ``resolve_hw(None)`` reads off the
+# attached device.  A kind missing here is an error, never a default.
+DEVICE_KIND_PRESETS = {"TPU v5 lite": "v5e", "TPU v5e": "v5e",
+                       "TPU v5": "v5p", "TPU v5p": "v5p"}
+
+
 def resolve_hw(hw) -> dict:
-    """Resolve/validate an ``hw=`` argument: ``None`` → v5e, a preset name
-    from :data:`HW_PRESETS`, or a dict carrying all of :data:`HW_KEYS` as
-    positive finite numbers.  Raises ``ValueError`` naming the defect."""
+    """Resolve/validate an ``hw=`` argument: ``None`` → the preset of the
+    attached device (``jax.devices()[0].device_kind`` looked up in
+    :data:`DEVICE_KIND_PRESETS`), a preset name from :data:`HW_PRESETS`,
+    or a dict carrying all of :data:`HW_KEYS` as positive finite numbers.
+    Raises ``ValueError`` naming the defect — including a device kind with
+    no preset, so a roofline is never silently computed against another
+    chip's peaks."""
     if hw is None:
-        return dict(HW_V5E)
+        import jax
+
+        kind = jax.devices()[0].device_kind
+        if kind not in DEVICE_KIND_PRESETS:
+            raise ValueError(
+                f"no roofline preset for device kind {kind!r}; pass hw= "
+                f"explicitly (presets: {sorted(HW_PRESETS)})")
+        return dict(HW_PRESETS[DEVICE_KIND_PRESETS[kind]])
     if isinstance(hw, str):
         if hw not in HW_PRESETS:
             raise ValueError(

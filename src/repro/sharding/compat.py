@@ -1,59 +1,40 @@
-"""Version-compatibility shims for the ``shard_map`` API family.
+"""The one spelling of the ``shard_map``/mesh API family this repo uses.
 
-The repo targets the modern spelling (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``) but must also run on older jax
-releases where ``shard_map`` still lives in ``jax.experimental`` (with
-``check_rep``) and meshes have no ``axis_types``.  Every module that builds a
-mesh or a shard_map goes through this shim so the version probe happens in
-exactly one place.
+The repo pins jax 0.9 (``requirements.txt``): ``jax.shard_map`` with
+``check_vma``, meshes with explicit ``AxisType.Auto`` axes and
+``jax.lax.axis_size``.  Every module that builds a mesh or a shard_map goes
+through these helpers, so the conventions (replication checking off, Auto
+axes) live in one place.
 """
 
 from __future__ import annotations
 
 import jax
 
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking disabled, on any jax."""
-    if _HAS_NEW_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, check_vma=False, in_specs=in_specs, out_specs=out_specs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, check_rep=False, in_specs=in_specs, out_specs=out_specs
+    """``jax.shard_map`` with replication checking disabled."""
+    return jax.shard_map(
+        f, mesh=mesh, check_vma=False, in_specs=in_specs, out_specs=out_specs
     )
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            axis_shapes,
-            axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
-def axis_size(name):
-    """``jax.lax.axis_size`` inside a shard_map body, on any jax (older
-    releases constant-fold ``psum(1, name)`` to the axis size)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+axis_size = jax.lax.axis_size
 
 
 def make_mesh_from_devices(dev_array, axis_names):
-    """``jax.sharding.Mesh`` over an explicit device array, any jax."""
-    if _HAS_AXIS_TYPES:
-        return jax.sharding.Mesh(
-            dev_array,
-            axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.sharding.Mesh(dev_array, axis_names)
+    """``jax.sharding.Mesh`` over an explicit device array, Auto axes."""
+    return jax.sharding.Mesh(
+        dev_array,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )

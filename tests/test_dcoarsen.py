@@ -126,3 +126,32 @@ def test_vcycle_p_invariant(p8, p1):
     for name in p8:
         assert p8[name]["cut"] == p1[name]["cut"], (name, p8[name], p1[name])
         assert p8[name]["vcycle_labels_equal"] and p1[name]["vcycle_labels_equal"]
+
+
+def test_cluster_round_compiles_once_per_level():
+    # round 1's leader ids are placed on the mesh like every later round's
+    # output, so a level's clustering rounds share one compiled program
+    # (an unplaced first input compiled the round twice per level — on a
+    # TPU host that is 15–35 s per level)
+    import jax
+
+    from repro.distributed import dcoarsen
+    from repro.distributed.dgraph import shard_graph
+    from repro.distributed.dmultilevel import make_pe_mesh
+    from repro.graphs import grid2d
+
+    compiled = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    mesh, P = make_pe_mesh(1)
+    # a shape no other test clusters, so the round is compiled here
+    sg = shard_graph(grid2d(17, 19), P, mesh=mesh)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        dcoarsen.dcluster(mesh, sg, 64.0, jax.random.PRNGKey(0), rounds=3)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiled.count("jit(cluster_round)") == 1, compiled

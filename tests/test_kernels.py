@@ -87,8 +87,8 @@ def test_kernel_property_random_padded(n_tiles, deg, k, seed):
     cap = np.full(kp, -np.inf, np.float32)
     cap[:k] = rng.uniform(0, 3, k)
 
-    got = gain_scoreboard_pallas(
-        jnp.asarray(nbr_lab), jnp.asarray(nbr_w), jnp.asarray(labels),
+    got = gain_scoreboard_pallas(  # the kernel takes (D, N), returns (N,)
+        jnp.asarray(nbr_lab.T), jnp.asarray(nbr_w.T), jnp.asarray(labels),
         jnp.asarray(nw), jnp.asarray(cap), tile_n=128, deg_chunk=16,
         interpret=True,
     )
@@ -99,7 +99,7 @@ def test_kernel_property_random_padded(n_tiles, deg, k, seed):
     for name, x, y in zip(("own", "gain", "tgt"), got, want):
         x = np.nan_to_num(np.asarray(x, np.float64), neginf=-1e30)
         x = np.where(x < -1e29, -1e30, x)
-        y = np.nan_to_num(np.asarray(y, np.float64), neginf=-1e30)
+        y = np.nan_to_num(np.asarray(y, np.float64)[:, 0], neginf=-1e30)
         np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-4, err_msg=name)
 
 

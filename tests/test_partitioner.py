@@ -10,6 +10,7 @@ from repro.core import (
     block_weights,
     conn_dense,
     edge_cut,
+    exact_edge_cut,
     imbalance,
     jet_round,
     l_max,
@@ -54,6 +55,77 @@ def test_ring_cut_two_blocks():
     g = ring(16)
     labels = jnp.asarray(([0] * 8) + ([1] * 8), dtype=jnp.int32)
     assert float(edge_cut(g, labels)) == 2.0  # two boundary edges
+
+
+def test_exact_edge_cut_beyond_fp32_range():
+    """The reported cut stays exact where the directed total passes 2^24
+    (here ~8e8, odd integer weights < 2^14): one fp32 accumulator rounds
+    it, the chunked float64 total does not."""
+    from repro.core.graph import from_coo
+    from repro.distributed.dgraph import (
+        labels_to_sharded,
+        shard_graph,
+        sharded_edge_cut,
+    )
+
+    rng = np.random.default_rng(0)
+    n = 1 << 16
+    u = np.arange(n)
+    w = rng.integers(1, 8192, n).astype(np.float32) * 2 - 1
+    g = from_coo(n, u, (u + 1) % n, w)
+    lab = rng.integers(0, 4, n).astype(np.int32)
+    src, col = np.asarray(g.src), np.asarray(g.col)
+    want = np.asarray(g.ew, np.float64)[lab[src] != lab[col]].sum() / 2
+    labels = jnp.asarray(lab)
+    assert exact_edge_cut(g, labels) == want
+    sg = shard_graph(g, 1)
+    assert sharded_edge_cut(sg, labels_to_sharded(sg, labels)) == want
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_top_candidates_equals_lexsort(seed):
+    """The greedy rebalancer's sort-free top-k selects exactly what the
+    lexsort it replaced selects, ties and -inf fillers included."""
+    from repro.refine.engine import top_candidates
+
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(100, 3000))
+    score = rng.integers(-3, 3, n).astype(np.float32)
+    score[rng.random(n) < 0.4] = -np.inf
+    tid = rng.integers(0, 50, n).astype(np.int32)
+    tid[rng.random(n) < 0.2] = np.iinfo(np.int32).max
+    got = top_candidates(jnp.asarray(score), jnp.asarray(tid), 128)
+    want = jnp.lexsort((jnp.asarray(tid), -jnp.asarray(score)))[:128]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("P_", [1, 4, 8, 9, 16])
+def test_bucket_rank_equals_stable_sort(P_):
+    """The contraction's bucket rank — a counting sort up to
+    COUNTING_SORT_MAX_P PEs, one stable argsort above — is each slot's rank
+    within its destination bucket in slot order."""
+    from repro.distributed.dcoarsen import bucket_rank
+
+    rng = np.random.default_rng(P_)
+    dest = rng.integers(0, P_ + 1, 2000).astype(np.int32)
+    got = np.asarray(bucket_rank(jnp.asarray(dest), P_))
+    order = np.argsort(dest, kind="stable")
+    want = np.empty_like(dest)
+    want[order] = np.arange(dest.size) - np.searchsorted(dest[order],
+                                                         dest[order])
+    live = dest < P_
+    np.testing.assert_array_equal(got[live], want[live])
+
+
+def test_cluster_shrinks_uniform_grid():
+    """On a uniform mesh the LP clustering must coarsen: before the
+    joiner/receiver coin flip every vertex chased its smallest-id
+    neighbour and a 2^16-vertex grid shrank by < 5 % in one level."""
+    from repro.core.coarsen import cluster
+
+    g = grid2d(256, 256)
+    cl = np.asarray(cluster(g, 128.0, jax.random.PRNGKey(0)))
+    assert len(np.unique(cl)) < g.n // 3
 
 
 def test_conn_dense_rowsum_equals_degreesum(grid):
