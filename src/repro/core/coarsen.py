@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.graph import Graph, from_coo
 
 
@@ -121,8 +122,10 @@ def cluster(
     cap = jnp.asarray(weight_cap, jnp.float32)
     for _ in range(rounds):
         key, sub = jax.random.split(key)
-        cl, moved = cluster_round(g, cl, cap, sub)
-        if int(moved) == 0:
+        with telemetry.span("cluster_round"):
+            telemetry.count_dispatch("cluster_round")
+            cl, moved = cluster_round(g, cl, cap, sub)
+        if telemetry.host_read("moved", moved) == 0:
             break
     # path-compress: follow leader once (LP may chain v→u→w between rounds)
     cl = cl[cl]
@@ -187,15 +190,20 @@ def coarsen_hierarchy(
     of (fine_graph, mapping) from finest to coarsest-1."""
     if coarsen_until is None:
         coarsen_until = max(512, 16 * k)
-    total_w = float(g.total_node_weight)
+    total_w = telemetry.host_read("total_w", g.total_node_weight, float)
     levels = []
     cur = g
     while cur.n > coarsen_until and len(levels) < max_levels:
-        # max cluster weight: a cluster must never exceed what fits a block
-        cap = max(total_w / coarsen_until, float(np.asarray(cur.nw).max()))
-        key, sub = jax.random.split(key)
-        cl = cluster(cur, cap, sub)
-        coarse, mapping = contract(cur, cl)
+        with telemetry.span("coarsen/level", level=len(levels), n=cur.n,
+                            m=cur.m):
+            # max cluster weight: a cluster must never exceed what fits a
+            # block
+            cap = max(total_w / coarsen_until, telemetry.host_read(
+                "cap", cur.nw, lambda nw: float(np.asarray(nw).max())))
+            key, sub = jax.random.split(key)
+            cl = cluster(cur, cap, sub)
+            with telemetry.span("contract"):
+                coarse, mapping = contract(cur, cl)
         if coarse.n >= (1.0 - shrink_min) * cur.n:
             break  # diminishing returns — stop coarsening
         levels.append((cur, mapping))

@@ -14,6 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core.graph import Graph
 from repro.core.partition import edge_cut, l_max, total_overload
 
@@ -70,12 +71,15 @@ def initial_partition(
 
     lmax = l_max(g, k, eps)
     best_labels, best_cut = None, float("inf")
-    for _ in range(n_restarts):
-        key, k1, k2 = jax.random.split(key, 3)
-        labels = greedy_balanced_seed(g.nw, k, k1)
-        labels = jet_refine(g, labels, k, eps, k2, rounds=2, patience=6, max_inner=24)
-        cut = float(edge_cut(g, labels))
-        ov = float(total_overload(g, labels, k, lmax))
+    for restart in range(n_restarts):
+        with telemetry.span("init/restart", restart=restart):
+            key, k1, k2 = jax.random.split(key, 3)
+            labels = greedy_balanced_seed(g.nw, k, k1)
+            labels = jet_refine(g, labels, k, eps, k2, rounds=2, patience=6,
+                                max_inner=24)
+            cut = telemetry.host_read("init_cut", edge_cut(g, labels), float)
+            ov = telemetry.host_read(
+                "init_ov", total_overload(g, labels, k, lmax), float)
         if ov <= 0 and cut < best_cut:
             best_labels, best_cut = labels, cut
     if best_labels is None:  # all restarts imbalanced — take the last anyway

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import coarsen as C
 from repro.core.config import UNSET, PartitionConfig, resolve_config
 from repro.core.graph import Graph
@@ -47,7 +48,8 @@ def level_trace_entry(n, eps, imb) -> dict:
     (``PartitionResult.level_trace`` / ``DPartitionResult.level_trace``;
     the P-invariance tests compare these dicts for exact equality across
     paths, so every recorder must build them here)."""
-    return {"n": int(n), "eps": float(eps), "imbalance": float(imb)}
+    return {"n": int(n), "eps": float(eps),
+            "imbalance": telemetry.host_read("level_imbalance", imb, float)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,7 +149,8 @@ def partition(
     level_graphs = [coarsest] + [fine for fine, _ in reversed(levels)]
     mappings = [mapping for _, mapping in reversed(levels)]
 
-    fp = (vc.fingerprint(cfg, seed, g.n, int(np.asarray(g.row_ptr)[-1]))
+    fp = (vc.fingerprint(cfg, seed, g.n, telemetry.host_read(
+        "m_live", g.row_ptr, lambda rp: int(np.asarray(rp)[-1])))
           if (policy or resume) else None)
     start, resume_step = 0, None
     if resume is not None:
@@ -182,7 +185,8 @@ def partition(
     return PartitionResult(
         labels=labels,
         cut=exact_edge_cut(g, labels),
-        imbalance=float(imbalance(g, labels, k)),
+        imbalance=telemetry.host_read("imbalance", imbalance(g, labels, k),
+                                      float),
         levels=n_levels,
         level_eps=eps_l,
         level_trace=tuple(trace) if trace_levels else None,
@@ -389,7 +393,8 @@ def finalize_result(s: dict, k: int, trace_levels: bool) -> PartitionResult:
     return PartitionResult(
         labels=s["labels"],
         cut=exact_edge_cut(s["g"], s["labels"]),
-        imbalance=float(imbalance(s["g"], s["labels"], k)),
+        imbalance=telemetry.host_read(
+            "imbalance", imbalance(s["g"], s["labels"], k), float),
         levels=s["n_levels"],
         level_eps=s["eps_l"],
         level_trace=tuple(s["trace"]) if trace_levels else None,

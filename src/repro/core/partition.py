@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.graph import Graph
 
 
@@ -55,7 +56,9 @@ def half_sum_exact(w: jax.Array) -> float:
     sum stays far below 2²⁴, so the result is exact where one fp32
     accumulator over all edges is not (a directed total above 2²⁴, e.g.
     rmat_21's ~6.7e7)."""
-    return float(np.sum(np.asarray(_chunk_sums(w), dtype=np.float64)) / 2.0)
+    return telemetry.host_read(
+        "cut", _chunk_sums(w),
+        lambda c: float(np.sum(np.asarray(c, dtype=np.float64)) / 2.0))
 
 
 def exact_edge_cut(g: Graph, labels: jax.Array) -> float:

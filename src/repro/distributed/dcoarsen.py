@@ -21,9 +21,11 @@ bodies over mesh axis ``"pe"``, reusing djet.py's ghost-exchange pattern:
   coarse :class:`ShardedGraph` — the coarse graph is *born sharded*; the
   fine graph is never gathered to the host.
 
-Only three scalars per level cross to the host (moved-vertex count, nc, and
-the max per-PE coarse edge count) — they pick the next level's static shapes,
-the BSP analogue of dKaMinPar's global per-level synchronisation.  With
+Only scalars cross to the host: per level the cluster-weight cap, the
+moved-vertex count of each LP round, nc and the max per-PE coarse edge count
+— they pick the next level's static shapes, the BSP analogue of dKaMinPar's
+global per-level synchronisation.  Each goes through
+``repro.telemetry.host_read``, which names and counts it.  With
 ``halo=True`` a fourth scalar (h_local, the max per-PE interface count) rides
 along and the hierarchy emits device-derived interface-only halo metadata
 per level (``halo.halo_from_sharded``) — the halo V-cycle never gathers a
@@ -43,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.core.coarsen import (
     grouped_best_cluster,
     joiner_mask,
@@ -320,10 +323,13 @@ def dcluster(mesh, sg: ShardedGraph, weight_cap: float, key,
     cap = jnp.asarray(weight_cap, jnp.float32)
     for _ in range(rounds):
         key, sub = jax.random.split(key)
-        cl, moved = fn(sg.src, sg.dst, sg.ew, sg.nw, owned, cl, sg.vtx_start,
-                       sub, cap)
-        if int(moved) == 0:
+        with telemetry.span("cluster_round"):
+            telemetry.count_dispatch("cluster_round")
+            cl, moved = fn(sg.src, sg.dst, sg.ew, sg.nw, owned, cl,
+                           sg.vtx_start, sub, cap)
+        if telemetry.host_read("moved", moved) == 0:
             break
+    telemetry.count_dispatch("compress")
     return _compress_fn(mesh, sg.n_local)(cl)
 
 
@@ -334,14 +340,17 @@ def dcontract(mesh, sg: ShardedGraph, cl) -> tuple[ShardedGraph, jax.Array, int]
     slot (labels project down as one gather in :func:`duncoarsen`).
     """
     owned = owned_mask(sg)
-    nc = int(_count_fn(sg.n_pad)(cl, owned))
+    telemetry.count_dispatch("count")
+    nc = telemetry.host_read("nc", _count_fn(sg.n_pad)(cl, owned))
     blk = max(1, -(-nc // sg.P))  # coarse vertices per PE (static next-shape)
 
     fn = _contract_fn(mesh, sg.P, sg.n_local, sg.m_local, blk)
-    src_c, dst_c, ew_c, nw_c, map_sh, mmax = fn(
-        sg.src, sg.dst, sg.ew, sg.nw, owned, cl
-    )
-    m_local_c = max(1, int(mmax))
+    with telemetry.span("contract"):
+        telemetry.count_dispatch("contract")
+        src_c, dst_c, ew_c, nw_c, map_sh, mmax = fn(
+            sg.src, sg.dst, sg.ew, sg.nw, owned, cl
+        )
+    m_local_c = max(1, telemetry.host_read("mmax", mmax))
     coarse = ShardedGraph(
         src=src_c[:, :m_local_c],
         dst=dst_c[:, :m_local_c],
@@ -363,9 +372,11 @@ def duncoarsen(mesh, fine_sg: ShardedGraph, map_sh, coarse_sg: ShardedGraph,
     """Project coarse labels to the finer level: one all_gather of the coarse
     label slices, then a per-PE gather through the fine→coarse mapping."""
     owned = owned_mask(fine_sg)
-    return _uncoarsen_fn(mesh, fine_sg.n_local, coarse_sg.n_local)(
-        map_sh, owned, lab_sh
-    )
+    with telemetry.span("uncoarsen"):
+        telemetry.count_dispatch("uncoarsen")
+        return _uncoarsen_fn(mesh, fine_sg.n_local, coarse_sg.n_local)(
+            map_sh, owned, lab_sh
+        )
 
 
 def dcoarsen_hierarchy(
@@ -393,15 +404,19 @@ def dcoarsen_hierarchy(
     """
     if coarsen_until is None:
         coarsen_until = max(512, 16 * k)
-    total_w = float(jnp.sum(sg0.nw))
+    total_w = telemetry.host_read("total_w", jnp.sum(sg0.nw), float)
     levels = []
     cur = sg0
     while cur.n_real > coarsen_until and len(levels) < max_levels:
-        # max cluster weight: a cluster must never exceed what fits a block
-        cap = max(total_w / coarsen_until, float(jnp.max(cur.nw)))
-        key, sub = jax.random.split(key)
-        cl = dcluster(mesh, cur, cap, sub)
-        coarse, map_sh, nc = dcontract(mesh, cur, cl)
+        with telemetry.span("coarsen/level", level=len(levels),
+                            n=cur.n_real, m=cur.P * cur.m_local):
+            # max cluster weight: a cluster must never exceed what fits a
+            # block
+            cap = max(total_w / coarsen_until,
+                      telemetry.host_read("cap", jnp.max(cur.nw), float))
+            key, sub = jax.random.split(key)
+            cl = dcluster(mesh, cur, cap, sub)
+            coarse, map_sh, nc = dcontract(mesh, cur, cl)
         if nc >= (1.0 - shrink_min) * cur.n_real:
             break  # diminishing returns — stop coarsening
         levels.append((cur, map_sh, coarse))

@@ -31,12 +31,14 @@ halo layout is derived from each sharded level directly.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import vcycle as vc
 from repro.core import coarsen as C
 from repro.core.config import UNSET, PartitionConfig, resolve_config
@@ -66,6 +68,11 @@ from repro.refine.variants import Variant
 from repro.sharding.compat import make_mesh
 
 
+# per-process id of a dpartition call, on its span: the spans of one job
+# share it
+_CALL_IDS = itertools.count()
+
+
 @dataclasses.dataclass(frozen=True)
 class DPartitionResult:
     labels: jax.Array
@@ -75,7 +82,7 @@ class DPartitionResult:
     P: int
     # phase wall times in seconds, only populated by dpartition(timing=True)
     # (timing adds block_until_ready syncs at the phase boundaries, so it is
-    # opt-in; keys: coarsen_s, init_s, refine_s — see benchmarks/bench.py)
+    # opt-in; keys: coarsen_s, init_s, refine_s — read by bench/metrics)
     timings: dict | None = None
     # per-level tolerances eps_l actually targeted, coarsest → finest
     level_eps: tuple = ()
@@ -88,29 +95,41 @@ class DPartitionResult:
     # the envelope rules resolved (halo None under the all-gather protocol),
     # so a fallback from the Pallas kernels to jnp is visible
     level_backends: tuple = ()
+    # this call's {"host_reads": {site: n}, "dispatches": {program: n}}
+    # (repro.telemetry), always populated
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 class _PhaseTimer:
-    """Accumulates per-phase wall time around explicit sync points; when
-    disabled every call is a no-op (no syncs added to the V-cycle)."""
+    """Opens a host span per phase (``coarsen``, ``init``, ``refine``) and,
+    when enabled, accumulates the phase's wall time (``<phase>_s``) around
+    explicit sync points; disabled, it adds no sync to the V-cycle."""
 
     def __init__(self, enabled: bool):
         self.enabled = enabled
         self.acc: dict[str, float] = {}
         self._t0 = 0.0
+        self._phase = ""
+        self._span = None
 
-    def start(self, sync=None):
+    def start(self, phase: str, sync=None):
         if self.enabled:
             if sync is not None:
                 jax.block_until_ready(sync)
             self._t0 = time.perf_counter()
+        self._phase = phase
+        self._span = telemetry.span(phase)
+        self._span.__enter__()
 
-    def stop(self, phase: str, sync=None):
+    def stop(self, sync=None):
         if self.enabled:
             if sync is not None:
                 jax.block_until_ready(sync)
-            self.acc[phase] = self.acc.get(phase, 0.0) + (
+            key = f"{self._phase}_s"
+            self.acc[key] = self.acc.get(key, 0.0) + (
                 time.perf_counter() - self._t0)
+        self._span.__exit__(None, None, None)
+        self._span = None
 
     def result(self) -> dict | None:
         return dict(self.acc) if self.enabled else None
@@ -132,7 +151,8 @@ def _dl_max(sg: ShardedGraph, k: int, eps: float):
 def _dimbalance(sg: ShardedGraph, lab_sh, k: int) -> float:
     """Imbalance of a sharded labelling — padding slots carry zero weight,
     so they contribute nothing to the block weights."""
-    return float(sharded_imbalance(sg, lab_sh, k))
+    return telemetry.host_read("imbalance", sharded_imbalance(sg, lab_sh, k),
+                               float)
 
 
 def _drefine_sharded(mesh, sg: ShardedGraph, lab_sh, k, lmax, key,
@@ -205,10 +225,10 @@ def _dpartition_host_coarsen(mesh, g, k, eps, key, k_coarse, k_init, var,
                              halo_uniform, timer, sched, trace_levels,
                              policy=None, resume=None, fp=None):
     """Fallback: centralised coarsening, per-level re-sharded refinement."""
-    timer.start()
+    timer.start("coarsen")
     levels, coarsest = C.coarsen_hierarchy(g, k, k_coarse,
                                            coarsen_until=coarsen_until)
-    timer.stop("coarsen_s", coarsest.nw)
+    timer.stop(coarsest.nw)
     n_levels = len(levels) + 1
     level_graphs = [coarsest] + [fine for fine, _ in reversed(levels)]
     mappings = [mapping for _, mapping in reversed(levels)]
@@ -227,9 +247,9 @@ def _dpartition_host_coarsen(mesh, g, k, eps, key, k_coarse, k_init, var,
         labels, key = jnp.asarray(lab_h), jnp.asarray(key_h)
         start = resume_step
     else:
-        timer.start()
+        timer.start("init")
         labels = initial_partition(coarsest, k, eps, k_init)
-        timer.stop("init_s", labels)
+        timer.stop(labels)
         if policy is not None:
             vc.save_step(policy, 0, labels, key, fp)
 
@@ -241,19 +261,23 @@ def _dpartition_host_coarsen(mesh, g, k, eps, key, k_coarse, k_init, var,
             trace.append(level_trace_entry(lvl_g.n, e,
                                            imbalance(lvl_g, lab, k)))
 
-    timer.start()
+    timer.start("refine")
     for j in range(start, n_levels):
         if j > 0:
             labels = labels[mappings[j - 1]]
         key, sub = jax.random.split(key)
-        labels, used = _drefine_level(
-            mesh, level_graphs[j], labels, k, eps_l[j], sub, var, patience,
-            max_inner, halo=halo, gain=gain, halo_uniform=halo_uniform)
+        with telemetry.span("refine/level", level=j,
+                            n=level_graphs[j].n) as sp:
+            labels, used = _drefine_level(
+                mesh, level_graphs[j], labels, k, eps_l[j], sub, var,
+                patience, max_inner, halo=halo, gain=gain,
+                halo_uniform=halo_uniform)
+            sp.set_metadata(gain=used["gain"])
         backends.append(dict(n=level_graphs[j].n, **used))
         _record(level_graphs[j], labels, eps_l[j])
         if policy is not None and policy.want_step(j, n_levels):
             vc.save_step(policy, j + 1, labels, key, fp)
-    timer.stop("refine_s", labels)
+    timer.stop(labels)
     return labels, n_levels, eps_l, trace, resume_step, backends
 
 
@@ -270,9 +294,10 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
     ``g`` may already be a :class:`ShardedGraph` (the out-of-core ingest
     path) — it is used as-is instead of re-sharding a host Graph."""
     P_ = mesh.devices.size
-    sg0 = (g if isinstance(g, ShardedGraph)
-           else shard_graph(g, P_, mesh=mesh))
-    timer.start(sg0.nw)
+    with telemetry.span("shard"):
+        sg0 = (g if isinstance(g, ShardedGraph)
+               else shard_graph(g, P_, mesh=mesh))
+    timer.start("coarsen", sg0.nw)
     if halo:
         levels, coarsest, halos = dcoarsen_hierarchy(
             mesh, sg0, k, k_coarse, coarsen_until=coarsen_until, halo=True)
@@ -280,7 +305,7 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
         levels, coarsest = dcoarsen_hierarchy(mesh, sg0, k, k_coarse,
                                               coarsen_until=coarsen_until)
         halos = [None] * (len(levels) + 1)
-    timer.stop("coarsen_s", coarsest.nw)
+    timer.stop(coarsest.nw)
     n_levels = len(levels) + 1
     # refinement-order level list: coarsest, then levels[i][0] fine graphs
     # (levels[i][2] is level_sgs[depth-1] — the coarse side of contraction i)
@@ -305,11 +330,11 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
         start = resume_step
     else:
         # initial partitioning on the (small) centralised coarsest graph
-        timer.start()
+        timer.start("init")
         gc = sharded_to_graph(coarsest)
         labels = initial_partition(gc, k, eps, k_init)
         lab_sh = labels_to_sharded(coarsest, labels)
-        timer.stop("init_s", lab_sh)
+        timer.stop(lab_sh)
         if policy is not None:
             vc.save_step(policy, 0, labels_from_sharded(coarsest, lab_sh),
                          key, fp)
@@ -319,10 +344,10 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
 
     def _record(lvl_sg, lab, e):
         if trace_levels:
-            trace.append(level_trace_entry(lvl_sg.n_real, e,
-                                           _dimbalance(lvl_sg, lab, k)))
+            trace.append(level_trace_entry(
+                lvl_sg.n_real, e, sharded_imbalance(lvl_sg, lab, k)))
 
-    timer.start()
+    timer.start("refine")
     for j in range(start, n_levels):
         if j == 0:
             sg_j, hs = coarsest, halos[-1]
@@ -332,16 +357,18 @@ def _dpartition_sharded_coarsen(mesh, g, k, eps, key, k_coarse, k_init,
             lab_sh = duncoarsen(mesh, fine_sg, map_sh, coarse_sg, lab_sh)
             sg_j, hs = fine_sg, halos[i]
         key, sub = jax.random.split(key)
-        lab_sh, used = _drefine_sharded(
-            mesh, sg_j, lab_sh, k, _dl_max(sg_j, k, eps_l[j]), sub, var,
-            patience, max_inner, gain=gain, hsg=hs,
-            halo_uniform=halo_uniform)
+        with telemetry.span("refine/level", level=j, n=sg_j.n_real) as sp:
+            lab_sh, used = _drefine_sharded(
+                mesh, sg_j, lab_sh, k, _dl_max(sg_j, k, eps_l[j]), sub, var,
+                patience, max_inner, gain=gain, hsg=hs,
+                halo_uniform=halo_uniform)
+            sp.set_metadata(gain=used["gain"])
         backends.append(dict(n=sg_j.n_real, **used))
         _record(sg_j, lab_sh, eps_l[j])
         if policy is not None and policy.want_step(j, n_levels):
             vc.save_step(policy, j + 1, labels_from_sharded(sg_j, lab_sh),
                          key, fp)
-    timer.stop("refine_s", lab_sh)
+    timer.stop(lab_sh)
 
     return labels_from_sharded(sg0, lab_sh), n_levels, eps_l, trace, \
         resume_step, backends
@@ -385,7 +412,9 @@ def dpartition(
     P-invariant but its own stream — see DESIGN.md §2).  ``timing=True``
     populates ``DPartitionResult.timings`` with per-phase wall seconds
     (coarsen_s / init_s / refine_s) at the cost of phase-boundary syncs —
-    the benchmark harness's hook (benchmarks/bench.py).
+    the benchmark harness's hook (``bench/metrics``).  Every call opens
+    host spans (``repro.telemetry``) and returns its own host-read and
+    dispatch counts in ``DPartitionResult.counters``.
 
     ``schedule`` names the per-level imbalance-tolerance schedule
     (``repro.refine.schedule``: ``constant`` / ``geometric`` / ``snap``) —
@@ -438,35 +467,43 @@ def dpartition(
     k_coarse, k_init, key = jax.random.split(key, 3)
     timer = _PhaseTimer(timing)
 
-    policy = cfg.ckpt
-    fp = None
-    if policy is not None or resume is not None:
-        if sharded_in:
-            n_g, m_live = g.n_real, int(jnp.sum(g.dst != PAD))
+    with telemetry.span("dpartition", call=next(_CALL_IDS)), \
+            telemetry.record() as rec:
+        policy = cfg.ckpt
+        fp = None
+        if policy is not None or resume is not None:
+            if sharded_in:
+                n_g = g.n_real
+                m_live = telemetry.host_read("m_live", jnp.sum(g.dst != PAD))
+            else:
+                n_g = g.n
+                m_live = telemetry.host_read(
+                    "m_live", g.row_ptr, lambda rp: int(np.asarray(rp)[-1]))
+            fp = vc.fingerprint(cfg, seed, n_g, m_live)
+
+        if coarsen == "host":
+            labels, n_levels, eps_l, trace, resume_step, backends = \
+                _dpartition_host_coarsen(
+                    mesh, g, k, eps, key, k_coarse, k_init, var,
+                    coarsen_until, patience, max_inner, halo, gain,
+                    halo_uniform, timer, sched, trace_levels, policy=policy,
+                    resume=resume, fp=fp)
         else:
-            n_g, m_live = g.n, int(np.asarray(g.row_ptr)[-1])
-        fp = vc.fingerprint(cfg, seed, n_g, m_live)
+            labels, n_levels, eps_l, trace, resume_step, backends = \
+                _dpartition_sharded_coarsen(
+                    mesh, g, k, eps, key, k_coarse, k_init, var,
+                    coarsen_until, patience, max_inner, halo, gain,
+                    halo_uniform, timer, sched, trace_levels, policy=policy,
+                    resume=resume, fp=fp)
 
-    if coarsen == "host":
-        labels, n_levels, eps_l, trace, resume_step, backends = \
-            _dpartition_host_coarsen(
-                mesh, g, k, eps, key, k_coarse, k_init, var, coarsen_until,
-                patience, max_inner, halo, gain, halo_uniform, timer, sched,
-                trace_levels, policy=policy, resume=resume, fp=fp)
-    else:
-        labels, n_levels, eps_l, trace, resume_step, backends = \
-            _dpartition_sharded_coarsen(
-                mesh, g, k, eps, key, k_coarse, k_init, var, coarsen_until,
-                patience, max_inner, halo, gain, halo_uniform, timer, sched,
-                trace_levels, policy=policy, resume=resume, fp=fp)
-
-    if sharded_in:
-        lab_fin = labels_to_sharded(g, labels)
-        cut = sharded_edge_cut(g, lab_fin)
-        imb = float(sharded_imbalance(g, lab_fin, k))
-    else:
-        cut = exact_edge_cut(g, labels)
-        imb = float(imbalance(g, labels, k))
+        if sharded_in:
+            lab_fin = labels_to_sharded(g, labels)
+            cut = sharded_edge_cut(g, lab_fin)
+            imb = _dimbalance(g, lab_fin, k)
+        else:
+            cut = exact_edge_cut(g, labels)
+            imb = telemetry.host_read("imbalance", imbalance(g, labels, k),
+                                      float)
     return DPartitionResult(
         labels=labels,
         cut=cut,
@@ -478,4 +515,5 @@ def dpartition(
         level_trace=tuple(trace) if trace_levels else None,
         resume_step=resume_step,
         level_backends=tuple(backends),
+        counters=rec.as_dict(),
     )

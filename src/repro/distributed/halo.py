@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.core.graph import PAD, Graph
 from repro.distributed.dgraph import ShardedGraph, owned_mask, shard_graph
 from repro.sharding.compat import shard_map
@@ -200,8 +201,11 @@ def halo_from_sharded(mesh, sg: ShardedGraph) -> HaloShardedGraph:
     only host transfer is the ``h_local`` scalar (it sizes the static
     exchange shapes).  The level graph is never gathered."""
     owned = owned_mask(sg)
-    h_local = max(1, int(_iface_count_fn(mesh, sg.P, sg.n_local)(
-        sg.src, sg.dst, owned)))
+    telemetry.count_dispatch("halo_count")
+    h_local = max(1, telemetry.host_read(
+        "h_local", _iface_count_fn(mesh, sg.P, sg.n_local)(
+            sg.src, sg.dst, owned)))
+    telemetry.count_dispatch("halo_build")
     src_h, dst_code, head_gid, my_gid, nw_h, owned_h, perm_loc, inv = (
         _halo_build_fn(mesh, sg.P, sg.n_local, sg.m_local, h_local)(
             sg.src, sg.dst, sg.nw, owned, sg.vtx_start))
@@ -226,7 +230,7 @@ def _halo_from_sharded_host(sg: ShardedGraph) -> HaloShardedGraph:
         sg.src, sg.dst, owned, pes)
     perm_loc, inv, n_if = jax.vmap(
         partial(_interface_perm_local, n_local=sg.n_local))(iface, owned)
-    h_local = max(1, int(jnp.max(n_if)))
+    h_local = max(1, telemetry.host_read("h_local", jnp.max(n_if)))
     src_h, dst_code, head_gid, my_gid, nw_h, owned_h = jax.vmap(
         partial(_halo_encode_local, P_=sg.P, n_local=sg.n_local,
                 h_local=h_local),

@@ -21,7 +21,9 @@ The module keeps two counters for the no-per-round-dispatch contract:
 ``TRACE_COUNT`` once per *trace* — a V-cycle over L levels must show
 exactly L dispatches (asserted in tests and reported by the scaling
 benchmark), where the pre-refactor drivers issued O(rounds · inner)
-dispatches per level.
+dispatches per level.  Each dispatch also goes to the per-call recorders
+of ``repro.telemetry`` (as ``refine_<kind>``), which give a
+``dpartition`` call its own counts.
 
 Factories are memoised on their static configuration, so repeated V-cycles
 over same-shaped levels reuse compiled programs.
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.refine import engine
 from repro.refine.schedule import ToleranceSchedule, resolve_schedule
 from repro.refine.comm import (
@@ -85,6 +88,7 @@ def _count_dispatch(kind: str) -> None:
     global DISPATCH_COUNT
     DISPATCH_COUNT += 1
     DISPATCHES[kind] = DISPATCHES.get(kind, 0) + 1
+    telemetry.count_dispatch(f"refine_{kind}")
 
 
 def _count_trace(kind: str) -> None:
@@ -121,7 +125,9 @@ def level_tolerances(schedule: str | ToleranceSchedule, eps: float,
 # --------------------------------------------------------------------------
 
 def graph_max_deg(g) -> int:
-    return max(int(np.asarray(g.degrees).max(initial=0)), 1)
+    return max(telemetry.host_read(
+        "max_deg", g.degrees,
+        lambda d: int(np.asarray(d).max(initial=0))), 1)
 
 
 @partial(jax.jit, static_argnames=("n_local",))
@@ -138,7 +144,9 @@ def _sharded_degrees(src, dst, n_local: int):
 def sharded_max_deg(src, dst, n_local: int) -> int:
     """True max degree of a sharded level — one scalar crosses to the host
     at setup time (it picks the static padded-adjacency width)."""
-    return max(int(_sharded_degrees(src, dst, n_local)), 1)
+    telemetry.count_dispatch("max_deg")
+    return max(telemetry.host_read(
+        "max_deg", _sharded_degrees(src, dst, n_local)), 1)
 
 
 def _need_max_deg(gain: str) -> bool:
@@ -416,13 +424,6 @@ def initial_partition_batched(bg, k, keys, lmaxs, n_restarts: int = 4,
     if not as_numpy:
         return labs, cuts, ovs
     return np.asarray(labs), np.asarray(cuts), np.asarray(ovs)
-
-
-def batched_cache_info() -> dict:
-    """Introspection for tests/bench: per-factory lru_cache statistics of
-    the bucketed batched programs."""
-    return {"level": _batched_level_fn.cache_info()._asdict(),
-            "init": _batched_init_fn.cache_info()._asdict()}
 
 
 def _lru_stats(cached_fn) -> dict:

@@ -28,6 +28,12 @@ argmax/top-k tie-break is index-order on ids that are order-isomorphic to
 global vertex ids in all backends, and all randomness is drawn in global
 vertex space — so any gain × comm × P combination replays the same move
 sequence from one seed (tests/test_refine_matrix.py).
+
+Device scopes (``jax.named_scope``, op metadata only): ``gain`` on every
+gain-backend call (:func:`gain_best`), ``move`` on the variant's move
+function, ``afterburner``, ``rebalance`` and ``cut`` on the functions of
+those names — so a device trace can split a level program's time by rung.
+Every variant, comm backend and the batched path carry them.
 """
 
 from __future__ import annotations
@@ -76,16 +82,25 @@ def overload_of(cm, ev: EdgeView, labels, k: int, lmax):
     return jnp.sum(jnp.maximum(bw - lmax, 0.0))
 
 
+@jax.named_scope("cut")
 def cut_of(cm, ev: EdgeView, labels):
     lv = _head_labels(cm, ev, labels)
     w = jnp.where(ev.live & (labels[ev.src] != lv), ev.ew, 0.0)
     return cm.psum(jnp.sum(w)) * 0.5
 
 
+def gain_best(gb, ev: EdgeView, lv_e, labels, capacity):
+    """``gb.best`` — (own, gain, target) per vertex — under the scope
+    ``gain``; every caller of a gain backend goes through here."""
+    with jax.named_scope("gain"):
+        return gb.best(ev, lv_e, labels, capacity)
+
+
 # --------------------------------------------------------------------------
 # Jet round: candidate set + afterburner (paper §2 "Jet Refinement")
 # --------------------------------------------------------------------------
 
+@jax.named_scope("afterburner")
 def afterburner_delta(cm, ev: EdgeView, labels, lv_e, gain, target, cand,
                       order: str = "gain"):
     """Assumed-state cut delta of every candidate move: exchange
@@ -144,7 +159,7 @@ def candidate_set(ev: EdgeView, labels, own, gain, target, tau, locked=None):
 def jet_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     """One Jet round; returns (new_labels, moved mask)."""
     lv_e = _head_labels(cm, ev, labels)
-    own, gain, target = gb.best(ev, lv_e, labels, None)
+    own, gain, target = gain_best(gb, ev, lv_e, labels, None)
     cand = candidate_set(ev, labels, own, gain, target, tau, locked)
     delta = afterburner_delta(cm, ev, labels, lv_e, gain, target, cand)
     move = cand & (delta >= 0.0)
@@ -161,7 +176,7 @@ def prob_pass(cm, gb, ev: EdgeView, labels, key, lmax, k: int):
     capacity = jnp.where(~overloaded, lmax - bw, NEG)
 
     lv_e = _head_labels(cm, ev, labels)
-    _, gain, target = gb.best(ev, lv_e, labels, capacity)
+    _, gain, target = gain_best(gb, ev, lv_e, labels, capacity)
 
     mover = overloaded[labels] & jnp.isfinite(gain) & ev.owned & (ev.nw > 0)
     bucket = _bucket_index(_relative_gain(gain, ev.nw))
@@ -243,7 +258,7 @@ def greedy_epoch(cm, gb, ev: EdgeView, labels, lmax, k: int,
     capacity = jnp.where(~overloaded, lmax - bw, NEG)
 
     lv_e = _head_labels(cm, ev, labels)
-    _, gain, target = gb.best(ev, lv_e, labels, capacity)
+    _, gain, target = gain_best(gb, ev, lv_e, labels, capacity)
 
     mover = overloaded[labels] & jnp.isfinite(gain) & ev.owned
     score = jnp.where(mover, _relative_gain(gain, ev.nw), NEG)
@@ -288,6 +303,7 @@ def greedy_epoch(cm, gb, ev: EdgeView, labels, lmax, k: int,
 # Rebalance driver: greedy epochs + <10 % progress escalation (paper §2)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("rebalance")
 def rebalance_loop(cm, gb, ev: EdgeView, labels, key, lmax, k: int,
                    max_epochs: int = 32, ncand: int = GREEDY_NCAND):
     """Returns (labels, overload, epochs, prob_passes)."""
@@ -338,7 +354,8 @@ def jet_inner(cm, gb, ev: EdgeView, labels, tau, lmax, key, k: int,
     def body(s):
         labels, locked, best_labels, best_cut, since, it, key = s
         key, k_reb = jax.random.split(key)
-        labels, moved = move_fn(cm, gb, ev, labels, locked, tau, k)
+        with jax.named_scope("move"):
+            labels, moved = move_fn(cm, gb, ev, labels, locked, tau, k)
         labels, ov, _, _ = rebalance_loop(cm, gb, ev, labels, k_reb, lmax, k)
         cut = cut_of(cm, ev, labels)
         improved = (ov <= 0) & (cut < best_cut)
@@ -384,7 +401,7 @@ def lp_round(cm, gb, ev: EdgeView, labels, key, lmax, k: int):
     bw = block_weights(cm, ev, labels, k)
     capacity = lmax - bw
     lv_e = _head_labels(cm, ev, labels)
-    _, gain, target = gb.best(ev, lv_e, labels, capacity)
+    _, gain, target = gain_best(gb, ev, lv_e, labels, capacity)
     want = (gain > 0) & jnp.isfinite(gain) & ev.owned
 
     w_in = cm.psum(jax.ops.segment_sum(

@@ -88,7 +88,7 @@ def jetlp_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     strictly positive assumed-state delta (zero-delta shuffles of admitted
     bad moves are what oscillates without a lock)."""
     lv_e = engine._head_labels(cm, ev, labels)
-    own, gain, target = gb.best(ev, lv_e, labels, None)
+    own, gain, target = engine.gain_best(gb, ev, lv_e, labels, None)
     cand = engine.candidate_set(ev, labels, own, gain, target, tau)
     delta = engine.afterburner_delta(cm, ev, labels, lv_e, gain, target, cand)
     move = cand & jnp.where(gain < 0, delta > 0.0, delta >= 0.0)
@@ -101,7 +101,7 @@ def jet_h_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     strictly positive gain.  The mean is an exact psum'd fp32
     integer-sum ratio, so the heavy mask is identical in every backend."""
     lv_e = engine._head_labels(cm, ev, labels)
-    own, gain, target = gb.best(ev, lv_e, labels, None)
+    own, gain, target = engine.gain_best(gb, ev, lv_e, labels, None)
 
     # level-invariant, recomputed per iteration: two *scalar* psums, noise
     # next to the O(n) label exchange every iteration already performs
@@ -128,7 +128,7 @@ def jet_v_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     to global ids in every backend, so the determinism contract extends
     for free."""
     lv_e = engine._head_labels(cm, ev, labels)
-    own, gain, target = gb.best(ev, lv_e, labels, None)
+    own, gain, target = engine.gain_best(gb, ev, lv_e, labels, None)
     cand = engine.candidate_set(ev, labels, own, gain, target, tau, locked)
     delta = engine.afterburner_delta(cm, ev, labels, lv_e, gain, target, cand,
                                      order="vertex")
