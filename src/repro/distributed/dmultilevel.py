@@ -91,9 +91,11 @@ class DPartitionResult:
     level_trace: tuple | None = None
     # committed snapshot step the V-cycle restarted from (None = fresh run)
     resume_step: int | None = None
-    # per refined level (coarsest → finest): {n, gain, halo} — the backends
-    # the envelope rules resolved (halo None under the all-gather protocol),
-    # so a fallback from the Pallas kernels to jnp is visible
+    # per refined level (coarsest → finest): {n, gain, halo, afterburner}
+    # — the backends the envelope rules resolved (halo None under the
+    # all-gather protocol), so a fallback from the Pallas kernels to jnp is
+    # visible, and the edge layout of the afterburner ("dense" beside the
+    # Pallas gain, "slot" beside jnp, None for lp variants)
     level_backends: tuple = ()
     # this call's {"host_reads": {site: n}, "dispatches": {program: n}}
     # (repro.telemetry), always populated

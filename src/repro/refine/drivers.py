@@ -49,7 +49,7 @@ from repro.refine.comm import (
     edge_view_from_graph,
     halo_edge_view,
 )
-from repro.refine.gain import make_gain, resolve_gain
+from repro.refine.gain import EDGE_LAYOUT, make_gain, resolve_gain
 from repro.refine.variants import resolve_variant
 from repro.sharding.compat import shard_map
 
@@ -153,6 +153,16 @@ def _need_max_deg(gain: str) -> bool:
     return gain in ("pallas", "auto")
 
 
+def _level_backends(gain_kind: str, halo_kind: str | None,
+                    variant: str) -> dict:
+    """A level program's ``run.backends``: the gain and halo backends the
+    fallback rules resolved, and the edge layout its afterburner runs in
+    (``EDGE_LAYOUT``; None for lp-mode variants, which run none)."""
+    jet = resolve_variant(variant).mode == "jet"
+    return {"gain": gain_kind, "halo": halo_kind,
+            "afterburner": EDGE_LAYOUT[gain_kind] if jet else None}
+
+
 # --------------------------------------------------------------------------
 # single device
 # --------------------------------------------------------------------------
@@ -239,7 +249,8 @@ def make_refine_level_sharded(mesh, sg, k, *, rounds_taus, patience=12,
     ``rounds_taus`` is the temperature vector; ``variant`` names the
     registered move-generation rule (lp-mode variants ignore the taus).
     ``run.backends`` names the backends the fallback rules resolved
-    (``{"gain": ..., "halo": None}`` — this protocol has no halo kernel).
+    (:func:`_level_backends`; ``"halo": None`` — this protocol has no halo
+    kernel).
     """
     from repro.distributed.dgraph import owned_mask
 
@@ -258,7 +269,7 @@ def make_refine_level_sharded(mesh, sg, k, *, rounds_taus, patience=12,
         return fn(sg.src, sg.dst, sg.ew, sg.nw, owned, sg.vtx_start, lab_sh,
                   key, jnp.float32(lmax), taus)
 
-    run.backends = {"gain": gain_kind, "halo": None}
+    run.backends = _level_backends(gain_kind, None, variant)
     return run
 
 
@@ -546,5 +557,5 @@ def make_refine_level_halo(mesh, hsg, k, *, rounds_taus, patience=12,
                   hsg.my_gid, hsg.owned, hsg.inv_perm, hsg.perm_loc,
                   hsg.gstart, lab_sh, key, jnp.float32(lmax), taus)
 
-    run.backends = {"gain": gain_kind, "halo": halo_kind}
+    run.backends = _level_backends(gain_kind, halo_kind, variant)
     return run

@@ -101,7 +101,7 @@ def gain_best(gb, ev: EdgeView, lv_e, labels, capacity):
 # --------------------------------------------------------------------------
 
 @jax.named_scope("afterburner")
-def afterburner_delta(cm, ev: EdgeView, labels, lv_e, gain, target, cand,
+def afterburner_delta(cm, lay, labels, lv_e, gain, target, cand,
                       order: str = "gain"):
     """Assumed-state cut delta of every candidate move: exchange
     (g(v), target, ∈M); u precedes v iff (g(u), −u) > (g(v), −v) in the
@@ -118,29 +118,33 @@ def afterburner_delta(cm, ev: EdgeView, labels, lv_e, gain, target, cand,
     order trades it for one fewer exchange and relies on the level
     driver's best-balanced tracking instead.  Both orders are
     order-isomorphic to global vertex ids in every backend, so the
-    determinism contract holds for either."""
-    tu = cm.lookup(ev, cm.exchange(target), target)
-    cu = cm.lookup(ev, cm.exchange(cand), cand)
+    determinism contract holds for either.
 
+    ``lay`` is the edge layout the sums run in (``gb.layout(ev)``): the
+    m-slot :class:`EdgeView`, or the gain kernel's (D, N) ``DenseView``,
+    where a tail value is a broadcast and a row sum a column sum.
+    ``lv_e`` is the per-slot head labels the gain backend read."""
     if order == "vertex":
-        precede = cu & (ev.head_tid < ev.my_tid[ev.src])
+        tu, cu, _ = lay.head_moves(cm, target, cand)
+        precede = cu & (lay.head_tid < lay.tails(lay.my_tid))
     elif order == "gain":
         gmask = jnp.where(cand, gain, NEG)
-        gu = cm.lookup(ev, cm.exchange(gmask), gmask)
-        gv = gain[ev.src]
+        tu, cu, gu = lay.head_moves(cm, target, cand, gmask)
+        gv = lay.tails(gain)
         precede = cu & ((gu > gv)
-                        | ((gu == gv) & (ev.head_tid < ev.my_tid[ev.src])))
+                        | ((gu == gv)
+                           & (lay.head_tid < lay.tails(lay.my_tid))))
     else:
         raise ValueError(f"afterburner order must be 'gain' or 'vertex', "
                          f"got {order!r}")
-    assumed = jnp.where(precede, tu, lv_e)
+    assumed = jnp.where(precede, tu, lay.relayout(lv_e))
 
-    w = jnp.where(ev.live, ev.ew, 0.0)
-    tv = target[ev.src]
-    lown = labels[ev.src]
+    w = jnp.where(lay.live, lay.ew, 0.0)
+    tv = lay.tails(target)
+    lown = lay.tails(labels)
     delta_e = w * ((assumed == tv).astype(w.dtype)
                    - (assumed == lown).astype(w.dtype))
-    return jax.ops.segment_sum(delta_e, ev.src, num_segments=ev.n_local)
+    return lay.rowsum(delta_e)
 
 
 def candidate_set(ev: EdgeView, labels, own, gain, target, tau, locked=None):
@@ -161,7 +165,8 @@ def jet_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     lv_e = _head_labels(cm, ev, labels)
     own, gain, target = gain_best(gb, ev, lv_e, labels, None)
     cand = candidate_set(ev, labels, own, gain, target, tau, locked)
-    delta = afterburner_delta(cm, ev, labels, lv_e, gain, target, cand)
+    delta = afterburner_delta(cm, gb.layout(ev), labels, lv_e, gain, target,
+                              cand)
     move = cand & (delta >= 0.0)
     return jnp.where(move, target, labels), move
 

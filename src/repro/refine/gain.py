@@ -22,6 +22,13 @@ Both backends compute bit-identical results on integer-weight graphs (fp32
 sums of integers < 2²⁴ are exact; argmax tie-breaks are index-order in
 both), which is what lets the determinism contract span the gain axis of
 the backend matrix.
+
+Each backend also names the edge layout the afterburner runs in beside it
+(``layout``; :data:`EDGE_LAYOUT` by backend name): the Pallas backend's
+padded adjacency is sized by the level's max_deg and k, so its (D, N)
+:class:`~repro.refine.comm.DenseView` serves the afterburner too; past
+that envelope a (D, N) table would be far larger than m, and the jnp
+backend keeps the m-slot :class:`~repro.refine.comm.EdgeView`.
 """
 
 from __future__ import annotations
@@ -32,9 +39,13 @@ import jax.numpy as jnp
 from repro.kernels import default_interpret
 from repro.kernels.gain.kernel import LANE, gain_scoreboard_pallas
 from repro.kernels.gain.kernel import round_up as _round_up
+from repro.refine.comm import dense_view
 
 PALLAS_MAX_DEG = 2048  # DESIGN.md §5: VMEM envelope of the scoreboard kernel
 PALLAS_MAX_K = 1024
+
+# the kind of the layout each backend's ``layout`` returns
+EDGE_LAYOUT = {"jnp": "slot", "pallas": "dense"}
 
 
 def resolve_gain(kind: str, k: int, max_deg: int | None) -> str:
@@ -76,6 +87,10 @@ class JnpGain:
 
     def __init__(self, k: int):
         self.k = k
+
+    def layout(self, ev):
+        """The afterburner's edge layout: the m slots of ``ev`` itself."""
+        return ev
 
     def best(self, ev, lv_e, labels, capacity):
         n_loc = ev.n_local
@@ -146,23 +161,26 @@ class PallasGain:
         self.nbr_w = jnp.zeros((d, n_pad), jnp.float32).at[cols, rows].set(
             jnp.where(ok, ev.ew, 0.0), mode="drop"
         )
+        self.dense = dense_view(ev, self.eslot, self.nbr_w)
         self.n_loc = n_loc
         self.n_pad = n_pad
 
-    def best(self, ev, lv_e, labels, capacity):
-        from repro.core.graph import PAD  # deferred: core↔refine cycle
+    def layout(self, ev):
+        """The afterburner's edge layout: the level's (D, N)
+        :class:`~repro.refine.comm.DenseView`."""
+        return self.dense
 
+    def best(self, ev, lv_e, labels, capacity):
         k_pad = _round_up(self.k, LANE)
         cap_k = (
             jnp.full((self.k,), jnp.inf, jnp.float32)
             if capacity is None else capacity
         )
         cap = jnp.full((k_pad,), -jnp.inf, jnp.float32).at[: self.k].set(cap_k)
-        lv_ext = jnp.concatenate(
-            [jnp.where(ev.live, lv_e, PAD).astype(jnp.int32),
-             jnp.full((1,), PAD, jnp.int32)]
-        )
-        nbr_lab = lv_ext[self.eslot]
+        # eslot names live slots only, so dead slots' lv_e is never read;
+        # the afterburner relayouts lv_e the same way, and XLA computes
+        # the one table for both
+        nbr_lab = self.dense.relayout(lv_e)
         pad = self.n_pad - self.n_loc
         lab_p = jnp.pad(labels, (0, pad))
         nw_p = jnp.pad(ev.nw, (0, pad))

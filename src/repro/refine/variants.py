@@ -90,7 +90,8 @@ def jetlp_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     lv_e = engine._head_labels(cm, ev, labels)
     own, gain, target = engine.gain_best(gb, ev, lv_e, labels, None)
     cand = engine.candidate_set(ev, labels, own, gain, target, tau)
-    delta = engine.afterburner_delta(cm, ev, labels, lv_e, gain, target, cand)
+    delta = engine.afterburner_delta(cm, gb.layout(ev), labels, lv_e, gain,
+                                     target, cand)
     move = cand & jnp.where(gain < 0, delta > 0.0, delta >= 0.0)
     return jnp.where(move, target, labels), move
 
@@ -112,7 +113,8 @@ def jet_h_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     cand = engine.candidate_set(ev, labels, own, gain, target, tau, locked)
     cand &= (~heavy) | (gain > 0.0)
 
-    delta = engine.afterburner_delta(cm, ev, labels, lv_e, gain, target, cand)
+    delta = engine.afterburner_delta(cm, gb.layout(ev), labels, lv_e, gain,
+                                     target, cand)
     move = cand & (delta >= 0.0)
     return jnp.where(move, target, labels), move
 
@@ -130,8 +132,8 @@ def jet_v_move(cm, gb, ev: EdgeView, labels, locked, tau, k: int):
     lv_e = engine._head_labels(cm, ev, labels)
     own, gain, target = engine.gain_best(gb, ev, lv_e, labels, None)
     cand = engine.candidate_set(ev, labels, own, gain, target, tau, locked)
-    delta = engine.afterburner_delta(cm, ev, labels, lv_e, gain, target, cand,
-                                     order="vertex")
+    delta = engine.afterburner_delta(cm, gb.layout(ev), labels, lv_e, gain,
+                                     target, cand, order="vertex")
     move = cand & (delta >= 0.0)
     return jnp.where(move, target, labels), move
 

@@ -13,9 +13,11 @@ parallel run must all collect the same tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels.gain.kernel import gain_scoreboard_pallas
@@ -68,6 +70,52 @@ def test_gain_scoreboard_compiles_for_v5e(one_chip, n, d, k):
             _spec((k,), jnp.float32, one_chip))
     _assert_kernel(gain_scoreboard_pallas.lower(
         *args, tile_n=256, deg_chunk=16).compile())
+
+
+# the finest level of the rgg2d-n17-k16 benchmark cell
+CELL_N, CELL_M, CELL_MAX_DEG, CELL_K = 131_072, 1_044_916, 21, 16
+
+
+def _scoped_ops(hlo: str, scope: str, op: str):
+    """Result shapes of the ``op`` instructions of a compiled module whose
+    innermost rung scope (``engine.py``'s named scopes) is ``scope``."""
+    rungs = ("afterburner", "cut", "gain", "move", "rebalance")
+    shapes = []
+    for line in hlo.splitlines():
+        m = re.search(rf"= (\S+) {op}\(.*op_name=\"([^\"]*)\"", line)
+        if m and [p for p in m.group(2).split("/") if p in rungs][-1:] == [
+                scope]:
+            shapes.append(m.group(1).split("{")[0])
+    return shapes
+
+
+def test_level_afterburner_reads_no_slot_gathers_for_v5e(one_chip):
+    """The fused all-gather level program at the benchmark cell's finest
+    shape, on the Pallas gain: its afterburner runs in the (D, N) layout,
+    so it gathers no m-slot array and scatters no segment sum; its reads
+    are (D, N) lookups."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.refine.drivers import _sharded_level_fn
+
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("pe",))
+    _, fn = _sharded_level_fn(mesh, CELL_K, CELL_N, CELL_N, 12, 64, "pallas",
+                              CELL_MAX_DEG, False, "jet")
+    row, rep = NamedSharding(mesh, P("pe", None)), NamedSharding(mesh, P())
+    n, m = CELL_N, CELL_M
+    args = (_spec((1, m), jnp.int32, row), _spec((1, m), jnp.int32, row),
+            _spec((1, m), jnp.float32, row), _spec((1, n), jnp.float32, row),
+            _spec((1, n), jnp.bool_, row),
+            _spec((1,), jnp.int32, NamedSharding(mesh, P("pe"))),
+            _spec((1, n), jnp.int32, row), _spec((2,), jnp.uint32, rep),
+            _spec((), jnp.float32, rep), _spec((4,), jnp.float32, rep))
+    hlo = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    d = 32  # 21 rounded up to the default degree chunk of 16
+    gathers = _scoped_ops(hlo, "afterburner", "gather")
+    assert gathers
+    assert all(s.endswith(f"[{d},{n}]") for s in gathers), gathers
+    assert not _scoped_ops(hlo, "afterburner", "scatter")
 
 
 def test_halo_apply_compiles_for_v5e(one_chip):
